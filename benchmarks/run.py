@@ -26,6 +26,7 @@ import time
 import traceback
 
 from benchmarks.common import Row, emit
+from repro.compile_cache import enable_compile_cache
 
 ALL = ("table1", "fig2", "fig4", "fig5", "fig7", "fig8", "kv_shortcut",
        "sharded")
@@ -185,6 +186,7 @@ def main(argv=None) -> int:
                          ">2x (a missing previous artifact still passes)")
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     wanted = [b for b in args.only.split(",") if b] or list(ALL)
 
     rows: list = []

@@ -8,14 +8,16 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.shortcut_eh import ShortcutEH
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(7)
     n_bulk, n_wave = 20_000, 400
-    keys = rng.choice(np.arange(1, 2**31, dtype=np.uint32),
-                      size=n_bulk + 4 * n_wave, replace=False)
+    keys = (rng.choice(2**31 - 1, size=n_bulk + 4 * n_wave, replace=False)
+            + 1).astype(np.uint32)
 
     with ShortcutEH(max_global_depth=14, bucket_slots=256, capacity=4096,
                     poll_interval=0.002, async_mapper=True) as sc:

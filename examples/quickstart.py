@@ -8,13 +8,15 @@ one level up, on a paged KV cache.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.shortcut_eh import ShortcutEH
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
-    keys = rng.choice(np.arange(1, 2**31, dtype=np.uint32), size=5000,
-                      replace=False)
+    keys = (rng.choice(2**31 - 1, size=5000, replace=False)
+            + 1).astype(np.uint32)
     vals = np.arange(5000, dtype=np.uint32)
 
     # the index: traditional directory (authoritative, synchronous) +
@@ -32,7 +34,7 @@ def main():
         print(f"lookup wave 1 ok; routed shortcut? "
               f"{index.routed_shortcut > 0}")
 
-        index.wait_in_sync()
+        assert index.wait_in_sync()
         print(f"mapper caught up; versions = {index.versions()}  "
               f"avg fan-in = {index.avg_fan_in():.2f}")
 
@@ -47,7 +49,7 @@ def main():
               f"(lookups keep working via the traditional path)")
         out = np.asarray(index.lookup(keys))
         assert (out == vals).all()
-        index.wait_in_sync()
+        assert index.wait_in_sync()
         print(f"resynced: {index.versions()}; "
               f"maintenance stats: {index.stats}")
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get
 from repro.kvcache import paged_cache as pc
 from repro.models import model as M
@@ -22,6 +23,7 @@ from repro.runtime.serve import (make_paged_serve_step, make_prefill_step,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_4b")
     ap.add_argument("--batch", type=int, default=4)

@@ -22,6 +22,7 @@ empty slot is a ghost from a different probe chain and must be ignored.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # -- the constants (defined here and nowhere else) ---------------------------
@@ -100,6 +101,35 @@ def probe_hit(probed: jnp.ndarray, key: jnp.ndarray):
     before = jnp.cumsum(empties.astype(jnp.int32)) - empties.astype(jnp.int32)
     live = hit & (before == 0)
     return jnp.any(live), jnp.argmax(live)
+
+
+def probe_rows(row_keys: jnp.ndarray, row_vals: jnp.ndarray,
+               keys: jnp.ndarray):
+    """:func:`probe_hit` for a whole tile of keys at once, as a rank mask
+    over each key's bucket row instead of a gather along its probe
+    sequence (the form the TPU's vector unit lowers).
+
+    ``row_keys``/``row_vals`` are (T, S): row t is key t's bucket row;
+    ``keys`` is (T, 1).  Lane j of row t sits at rank
+    ``(j - start_t) mod S`` of key t's cyclic probe sequence, so "a hit
+    after the first EMPTY is ignored" becomes "a hit counts only when
+    its rank is below the rank of the row's first EMPTY".  Returns
+    ``(found, value)``, both (T, 1); ``value`` is 0 where not found."""
+    slots = row_keys.shape[-1]
+    start = (hash_bucket(keys) % jnp.uint32(slots)).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row_keys.shape,
+                                    row_keys.ndim - 1)
+    rank = (lane - start + slots) % slots
+    empty = row_keys == jnp.uint32(EMPTY_SENTINEL)
+    first_empty = jnp.min(jnp.where(empty, rank, slots), axis=-1,
+                          keepdims=True)
+    live = (row_keys == keys.astype(jnp.uint32)) & (rank < first_empty)
+    hit = jnp.min(jnp.where(live, rank, slots), axis=-1, keepdims=True)
+    # ranks are a permutation of the lanes, so exactly one lane has the
+    # hit's rank; int32 because the vector unit reduces signed lanes
+    value = jnp.sum(jnp.where(rank == hit, row_vals, 0).astype(jnp.int32),
+                    axis=-1, keepdims=True)
+    return hit < slots, value.astype(jnp.uint32)
 
 
 def probe_slot(probed: jnp.ndarray, key: jnp.ndarray):

@@ -11,11 +11,8 @@ would have built — the partition is a *refinement*, not a different
 structure, which is why a sharded index answers every lookup bit-for-bit
 identically to a flat one over the same trace.
 
-What sharding buys (ISSUE/DESIGN.md §4):
+What sharding buys (DESIGN.md §4):
 
-  * **bounded per-shard view size** — each shard's directory/view stays
-    in the Pallas kernels' VMEM-resident regime (DESIGN.md §2.4) long
-    after a flat directory would have outgrown it;
   * **shard-local maintenance** — splits, doublings, create/update
     requests, version gates and route decisions touch exactly one
     shard's mapper; a doubling in shard 3 never collapses shard 5's
@@ -35,6 +32,12 @@ What sharding buys (ISSUE/DESIGN.md §4):
 ``num_shards=1`` degenerates to the flat index: same hash, same routing
 law, same maintenance protocol, and ``lookup`` delegates straight to the
 inner :class:`ShortcutEH`.
+
+What it does not buy: a smaller per-shard view.  Each shard's
+directory and composed view keep ``2**global_depth`` rows, of which only
+``2**(global_depth - shard_bits)`` are reachable (the slot keeps the
+shard bits), so the VMEM-resident kernels cap the whole index near 2^20
+slots whatever N is.  ``avg_fan_in`` counts the unreachable slots too.
 
 Skew note: within shard s every key shares its top ``shard_bits`` hash
 bits, so the first ``shard_bits`` doublings of a shard's directory are

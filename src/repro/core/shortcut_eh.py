@@ -252,8 +252,7 @@ class ShortcutEH:
         use = use and view is not None
         self.mapper.count_route(use)
         if use:
-            if self._cache is not None and \
-                    jax.default_backend() in ("tpu", "gpu"):
+            if self._cache is not None and jax.default_backend() == "tpu":
                 # resolve straight off the stacked primary: the kernel
                 # block-selects the shard via scalar prefetch, so no
                 # per-shard slice is ever materialized on device

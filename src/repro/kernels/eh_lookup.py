@@ -2,51 +2,49 @@
 
 Two access paths, mirroring §2 of the paper:
 
-  * :func:`eh_lookup`      — the *traditional* path: hash -> directory
-    gather -> bucket gather -> probe.  Two data-dependent indirections.
-  * :func:`shortcut_lookup`— the *shortcut* path: hash -> direct view
-    probe.  One indirection: the composed view (``rewiring.compose``) plays
-    the role of the page table having pre-resolved the mapping.
+  * the *traditional* path: hash -> directory read -> bucket row ->
+    probe.  Two data-dependent indirections.
+  * the *shortcut* path: hash -> view row -> probe.  One indirection:
+    the composed view (``rewiring.compose``) plays the role of the page
+    table having pre-resolved the mapping.
 
-Both exist in a **sharded** form (:func:`sharded_eh_lookup`,
-:func:`sharded_shortcut_lookup`) for the partitioned index
-(``core/sharded_eh.py``): the per-shard structures are stacked on a
-leading shard axis and the shard loop is a *grid dimension* of one
-``pallas_call`` — N shards share a single kernel specialization instead
-of recompiling (or even re-dispatching) per shard.  The single-shard
-entry points are the N=1 degenerate case of the same kernel, so there is
-exactly one lookup-kernel body in the tree (``_resolve_tile``).
+Every entry point is one ``pallas_call`` over a grid of (shard, key
+tile) with one shared body, :func:`_resolve_tile`:
 
-:func:`sharded_routed_lookup` is the **per-shard routed** form: it takes
-a per-shard ``two_level`` flag vector (scalar-prefetched alongside both
-depth vectors) and resolves each shard through the directory or the
-composed view *inside the same dispatch* — a mixed-sync shard group
-(some shards gated traditional, some shortcut-eligible) no longer
-demotes the whole batch.  The flag is uniform per grid cell, so each
-cell runs exactly one ``pl.when`` arm of the shared body.
+  * :func:`sharded_eh_lookup` / :func:`sharded_shortcut_lookup` — the
+    partitioned index (``core/sharded_eh.py``): per-shard structures
+    stacked on a leading shard axis, the shard loop a grid dimension, so
+    N shards share one kernel specialization.  :func:`eh_lookup` and
+    :func:`shortcut_lookup` are their N=1 case.
+  * :func:`sharded_routed_lookup` — per-shard routed: a scalar-prefetched
+    ``two_level`` flag per shard picks the directory or the composed
+    view *inside the same dispatch*; the flag is uniform per grid cell,
+    so each cell runs exactly one ``pl.when`` arm of the shared body.
+  * :func:`stacked_shortcut_lookup` — the flat (single-shard) path
+    against the stacked primary operand storage
+    (``runtime/operand_cache``, DESIGN.md §4.4): the shard index arrives
+    by scalar prefetch and the block index maps select that shard's
+    block of the ``(N, V, S)`` stack; no per-shard slice is materialized.
 
-:func:`stacked_shortcut_lookup` is the flat (single-shard) path against
-the stacked **primary** operand storage (``runtime/operand_cache``,
-DESIGN.md §4.4): the shard index arrives by scalar prefetch and the
-block index maps select that shard's block of the ``(N, V, S)`` stack
-directly — no per-shard slice is ever materialized on device.
+How a key tile resolves on the chip (DESIGN.md §2.4).  One shard's bucket
+pools (or composed view) are VMEM-resident for the whole shard: the block
+index is constant along the key-tile axis, so those blocks are single
+buffered.  VMEM lays a row out on 128 lanes, so a 64-slot u32 row takes a
+full 128-lane row: one shard's 2^14-row view pair occupies 16 MiB of VMEM,
+and ``vmem_limit_bytes`` is sized from the blocks.  The directory block
+lives in SMEM, where the scalar unit reads it.  Per tile:
 
-TPU adaptation notes (DESIGN.md §2): the VPU has no scatter/gather to HBM,
-so both kernels keep the directory and bucket pages VMEM-resident (block =
-one shard's full structure; for the assigned sizes — 2^14 slots x 64-slot
-buckets of u32 pairs — this is ~8 MiB, within VMEM; sharding is exactly
-what keeps *growing* structures inside this regime, DESIGN.md §2.4).  Per
-key-tile the kernel computes the multiplicative hashes vectorized on the
-VPU, then resolves the data-dependent row reads with a ``fori_loop`` of
-dynamic slices (sublane-dynamic addressing, which Mosaic supports on
-VMEM).  The probe itself is vectorized across the bucket row.
-Directories larger than VMEM are exactly the regime where the paper's
-lesson applies: don't chase pointers — compose the view first
-(``shortcut_lookup``), shard the structure, or fall back to the XLA
-gather path (``core.extendible_hashing``).
+  1. the VPU hashes the lane-major key tile into directory slots, and one
+     local DMA moves the slots to SMEM;
+  2. a scalar loop reads each key's row index (the directory read is the
+     traditional path's extra indirection) and copies that key's bucket
+     row into a (tile, S) gather buffer with dynamic sublane loads;
+  3. the probe runs on the whole tile at once as a rank mask over the
+     gathered rows (``hashing.probe_rows``), and a transpose returns the
+     per-key results to the lane-major output tile.
 
-``interpret=None`` auto-detects the execution mode (compiled on TPU,
-interpreted elsewhere — ``kernels/backend.py``).
+``tile`` must be a multiple of 128 (the lane width).  ``interpret=None``
+compiles on a TPU and interprets elsewhere (``kernels/backend.py``).
 """
 from __future__ import annotations
 
@@ -61,136 +59,180 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hashing
 from repro.kernels.backend import resolve_interpret
 
-# hashing.HASH_C1/C2 and the sentinels are python ints (NOT jnp scalars: a
-# traced module-level constant would be captured by the kernel, which
-# pallas forbids); cast at use sites.  Local aliases for readability.
-EMPTY_KEY = hashing.EMPTY_SENTINEL
+# hashing's sentinels are python ints (NOT jnp scalars: a traced
+# module-level constant would be captured by the kernel, which pallas
+# forbids); cast at use sites.
 MISS = hashing.MISS_SENTINEL
 
-
-def _probe_row(row_k, row_v, key, slots: int):
-    """Vectorized linear probe of one bucket row (slots,)->value or MISS.
-
-    Same masked-probe core as the XLA path (``hashing.probe_hit``); the
-    helpers trace cleanly inside the kernel because they only use
-    elementwise/cumsum/argmax ops the VPU supports."""
-    pos = hashing.probe_positions(key, slots)
-    found, j = hashing.probe_hit(row_k[pos], key)
-    return jnp.where(found, row_v[pos[j]], jnp.uint32(MISS))
+_LANES = 128
+_SUBLANES = 8
+_SCOPED_VMEM_DEFAULT = 16 << 20     # v5e's default scoped VMEM limit
+_VMEM_MARGIN = 4 << 20              # Mosaic's own internal scratch
 
 
-def _resolve_tile(keys, g, dir_ref, bk_ref, bv_ref, out_ref, *,
-                  tile: int, slots: int, two_level: bool):
-    """THE lookup body: resolve one key tile against one shard's pages.
-
-    Shared by the static kernels and both arms of the routed kernel, so
-    there is still exactly one probe loop in the tree."""
-    slot = hashing.dir_slot(hashing.hash_dir(keys), g)
-
-    def body(i, _):
-        key = keys[i]
-        s = slot[i]
-        if two_level:
-            row = dir_ref[0, s]         # indirection 1: directory
-        else:
-            row = s                     # shortcut: slot IS the row
-        row_k = bk_ref[0, row]          # indirection 2 (or 1): bucket page
-        row_v = bv_ref[0, row]
-        out_ref[0, i] = _probe_row(row_k, row_v, key, slots)
-        return 0
-
-    jax.lax.fori_loop(0, tile, body, 0)
+def _column(row):
+    """(1, T) lane-major -> (T, 1) sublane-major, via a 2-D transpose
+    (Mosaic has no vector reshape across the lane axis)."""
+    return jnp.transpose(jnp.broadcast_to(row, (_SUBLANES, row.shape[1])))[
+        :, :1]
 
 
-def _lookup_kernel(gd_ref, keys_ref, dir_ref, bk_ref, bv_ref, out_ref, *,
-                   tile: int, slots: int, two_level: bool):
+def _row(col):
+    """(T, 1) -> (1, T): the inverse of :func:`_column`."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], _LANES)))[
+        :1, :]
+
+
+def _resolve_tile(keys_ref, g, dir_ref, bk_ref, bv_ref, out_ref,
+                  rows_k, rows_v, slot_v, slot_s, sem):
+    """THE lookup body: resolve one (1, tile) key tile against one
+    shard's pages.  ``dir_ref`` None = shortcut (the slot IS the row)."""
+    keys = keys_ref[...]
+    slot_v[...] = hashing.dir_slot(hashing.hash_dir(keys), g)
+    copy = pltpu.make_async_copy(slot_v, slot_s, sem)
+    copy.start()
+    copy.wait()
+
+    def gather(i, carry):
+        slot = slot_s[0, i]
+        if dir_ref is None:
+            row = slot
+        else:                                  # indirection 1: directory
+            row = dir_ref[slot // _LANES, slot % _LANES]
+        rows_k[pl.ds(i, 1), :] = bk_ref[pl.ds(row, 1), :]
+        rows_v[pl.ds(i, 1), :] = bv_ref[pl.ds(row, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, keys.shape[1], gather, 0)
+    found, value = hashing.probe_rows(rows_k[...], rows_v[...],
+                                      _column(keys))
+    out_ref[...] = _row(jnp.where(found, value, jnp.uint32(MISS)))
+
+
+def _lookup_kernel(gd_ref, keys_ref, *refs, two_level: bool):
     """One (shard, key-tile) grid cell, single-mode (``two_level`` is a
-    *static* python bool baked into the specialization).
-
-    Blocks carry a leading unit shard dim; the shard's global depth comes
-    from the scalar-prefetch vector, indexed by the shard grid position —
-    the only per-shard scalar, which is what lets every shard share this
-    one specialization."""
-    g = gd_ref[pl.program_id(0)]
-    _resolve_tile(keys_ref[0], g, dir_ref, bk_ref, bv_ref, out_ref,
-                  tile=tile, slots=slots, two_level=two_level)
+    static python bool baked into the specialization).  The shard's
+    global depth comes from the scalar-prefetch vector — the only
+    per-shard scalar, which is what lets every shard share this one
+    specialization."""
+    if not two_level:
+        refs = (None,) + refs
+    _resolve_tile(keys_ref, gd_ref[pl.program_id(0)], *refs)
 
 
 def _routed_kernel(sc_ref, keys_ref, dir_ref, bk_ref, bv_ref, vk_ref,
-                   vv_ref, out_ref, *, tile: int, slots: int):
+                   vv_ref, out_ref, *scratch):
     """One (shard, key-tile) grid cell, per-shard routed.
 
     ``sc_ref`` is the packed (3, N) scalar-prefetch block: row 0 the
     per-shard ``two_level`` flags (1 → resolve traditionally through the
     directory, 0 → through the composed view), row 1 the traditional
-    global depths, row 2 the view log2 sizes.  The flag is uniform
-    across a grid cell (it is per *shard*), so each cell runs exactly
-    one ``pl.when`` arm — a mixed-sync shard group still fuses into ONE
-    dispatch instead of demoting the whole batch to the traditional
-    kernel."""
+    global depths, row 2 the view log2 sizes."""
     s = pl.program_id(0)
-    two_level = sc_ref[0, s]
-    keys = keys_ref[0]
 
-    @pl.when(two_level != 0)
+    @pl.when(sc_ref[0, s] != 0)
     def _traditional():
-        _resolve_tile(keys, sc_ref[1, s], dir_ref, bk_ref, bv_ref,
-                      out_ref, tile=tile, slots=slots, two_level=True)
+        _resolve_tile(keys_ref, sc_ref[1, s], dir_ref, bk_ref, bv_ref,
+                      out_ref, *scratch)
 
-    @pl.when(two_level == 0)
+    @pl.when(sc_ref[0, s] == 0)
     def _shortcut():
-        _resolve_tile(keys, sc_ref[2, s], dir_ref, vk_ref, vv_ref,
-                      out_ref, tile=tile, slots=slots, two_level=False)
+        _resolve_tile(keys_ref, sc_ref[2, s], None, vk_ref, vv_ref,
+                      out_ref, *scratch)
 
 
-def _run(keys, directory, bucket_keys, bucket_vals, global_depths, *,
-         two_level: bool, tile: int, interpret: Optional[bool]):
-    """Shared driver: keys (N, K); directory (N, D); buckets (N, C, S);
-    global_depths (N,).  Grid = (shards, key tiles); every shard reuses
-    the same compiled kernel — one ``pallas_call``, not N."""
-    N, n = keys.shape
+def _stacked_select_kernel(sc_ref, keys_ref, vk_ref, vv_ref, out_ref,
+                           *scratch):
+    """One key-tile grid cell against the shard block that the index maps
+    selected with ``sc_ref[0]``; ``sc_ref[1]`` is that shard's view log2."""
+    _resolve_tile(keys_ref, sc_ref[1], None, vk_ref, vv_ref, out_ref,
+                  *scratch)
+
+
+def _vmem_limit(pools, tile: int) -> int:
+    """Scoped VMEM for one grid cell: the single-buffered pool blocks, the
+    gather buffers and the double-buffered key/output tiles, every row
+    padded to whole 128-lane rows."""
+    lanes = -(-pools[0].shape[-1] // _LANES) * _LANES
+    resident = sum(p.shape[1] for p in pools) * lanes * 4
+    gather = 2 * tile * lanes * 4
+    tiles = 5 * _SUBLANES * tile * 4
+    return max(_SCOPED_VMEM_DEFAULT,
+               resident + gather + tiles + _VMEM_MARGIN)
+
+
+def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
+          interpret: Optional[bool]):
+    """What every entry point calls: ONE ``pallas_call`` over grid (key
+    rows, key tiles).
+
+    keys (B, n); directory (N, D) or None; pools: (N, R, S) arrays;
+    ``select(b, sc)`` maps grid row b (and the scalar-prefetch ref) to
+    the shard block its pages come from.  Returns (B, n) uint32."""
+    if tile % _LANES:
+        raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
+    B, n = keys.shape
     pad = (-n) % tile
-    if pad:
-        keys = jnp.pad(keys, ((0, 0), (0, pad)))
-    nt = (n + pad) // tile
-    D = directory.shape[1]
-    C, S = bucket_keys.shape[1:]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,          # per-shard global depths in SMEM
-        grid=(N, nt),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda s, i, gd: (s, i)),
-            pl.BlockSpec((1, D), lambda s, i, gd: (s, 0)),    # VMEM-resident
-            pl.BlockSpec((1, C, S), lambda s, i, gd: (s, 0, 0)),
-            pl.BlockSpec((1, C, S), lambda s, i, gd: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda s, i, gd: (s, i)),
-    )
-    kernel = functools.partial(_lookup_kernel, tile=tile, slots=S,
-                               two_level=two_level)
+    keys = jnp.pad(keys.astype(jnp.uint32), ((0, 0), (0, pad)))[:, None, :]
+    key_spec = pl.BlockSpec((None, 1, tile), lambda b, i, sc: (b, 0, i))
+
+    def per_shard(block, **kw):
+        return pl.BlockSpec((None,) + block,
+                            lambda b, i, sc: (select(b, sc), 0, 0),
+                            pipeline_mode=pl.Buffered(1), **kw)
+
+    in_specs, args = [key_spec], [keys]
+    if directory is not None:
+        # (N, D) -> (N, D/128, 128): a 2-D block the scalar unit indexes
+        # in SMEM; rows past 2**depth are never read, so zero padding is
+        # inert
+        N, D = directory.shape
+        dpad = (-D) % _LANES
+        directory = jnp.pad(directory.astype(jnp.int32),
+                            ((0, 0), (0, dpad))).reshape(N, -1, _LANES)
+        in_specs.append(per_shard(directory.shape[1:],
+                                  memory_space=pltpu.SMEM))
+        args.append(directory)
+    for p in pools:
+        in_specs.append(per_shard(p.shape[1:]))
+        args.append(p)
+    slots = pools[0].shape[-1]
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, n + pad), jnp.uint32),
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, (n + pad) // tile),
+            in_specs=in_specs, out_specs=key_spec,
+            scratch_shapes=[pltpu.VMEM((tile, slots), jnp.uint32),
+                            pltpu.VMEM((tile, slots), jnp.uint32),
+                            pltpu.VMEM((1, tile), jnp.int32),
+                            pltpu.SMEM((1, tile), jnp.int32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, n + pad), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(pools, tile)),
         interpret=resolve_interpret(interpret),
-    )(global_depths.astype(jnp.int32), keys.astype(jnp.uint32),
-      directory.astype(jnp.int32), bucket_keys, bucket_vals)
-    return out[:, :n]
+    )(scalars, *args)
+    return out[:, 0, :n]
+
+
+def _by_row(b, sc):
+    return b
 
 
 # ---------------------------------------------------------------------------
-# Single-shard entry points (N=1 degenerate case of the sharded kernel).
+# Single-shard entry points (N=1 case of the sharded kernel).
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def eh_lookup(keys, directory, bucket_keys, bucket_vals, global_depth, *,
               tile: int = 256, interpret: Optional[bool] = None):
-    """Traditional EH lookup: keys (N,) -> values (N,) (MISS on absent).
+    """Traditional EH lookup: keys (K,) -> values (K,) (MISS on absent).
 
     directory: (D,) int32; bucket_keys/vals: (C, S) uint32."""
-    return _run(keys[None], directory[None], bucket_keys[None],
-                bucket_vals[None],
-                jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
-                two_level=True, tile=tile, interpret=interpret)[0]
+    return sharded_eh_lookup(
+        keys[None], directory[None], bucket_keys[None], bucket_vals[None],
+        jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
+        tile=tile, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -199,10 +241,10 @@ def shortcut_lookup(keys, view_keys, view_vals, global_depth, *,
     """Shortcut lookup over the composed view: one indirection fewer.
 
     view_keys/vals: (2^g_cap, S) — slot-indexed bucket pages."""
-    dummy_dir = jnp.zeros((1, 1), jnp.int32)  # unused in shortcut mode
-    return _run(keys[None], dummy_dir, view_keys[None], view_vals[None],
-                jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
-                two_level=False, tile=tile, interpret=interpret)[0]
+    return sharded_shortcut_lookup(
+        keys[None], view_keys[None], view_vals[None],
+        jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
+        tile=tile, interpret=interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -217,11 +259,13 @@ def sharded_eh_lookup(keys, directories, bucket_keys, bucket_vals,
     """Traditional lookup across N stacked shards.
 
     keys: (N, K) — shard-bucketized, padded to a static per-shard
-    capacity (pad lanes return MISS and are dropped by the caller's
-    scatter-back); directories: (N, D); bucket_keys/vals: (N, C, S);
-    global_depths: (N,).  Returns (N, K) uint32."""
-    return _run(keys, directories, bucket_keys, bucket_vals, global_depths,
-                two_level=True, tile=tile, interpret=interpret)
+    capacity (pad lanes return MISS or a stray hit and are dropped by
+    the caller's scatter-back); directories: (N, D); bucket_keys/vals:
+    (N, C, S); global_depths: (N,).  Returns (N, K) uint32."""
+    return _call(functools.partial(_lookup_kernel, two_level=True),
+                 global_depths.astype(jnp.int32), keys, directories,
+                 (bucket_keys, bucket_vals), select=_by_row, tile=tile,
+                 interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -229,20 +273,10 @@ def sharded_shortcut_lookup(keys, view_keys, view_vals, global_depths, *,
                             tile: int = 256,
                             interpret: Optional[bool] = None):
     """Shortcut lookup across N stacked shards (views (N, V, S))."""
-    dummy_dir = jnp.zeros((keys.shape[0], 1), jnp.int32)
-    return _run(keys, dummy_dir, view_keys, view_vals, global_depths,
-                two_level=False, tile=tile, interpret=interpret)
-
-
-def _stacked_select_kernel(sc_ref, keys_ref, vk_ref, vv_ref, out_ref, *,
-                           tile: int, slots: int):
-    """One key-tile grid cell against ONE shard's block of the stacked
-    view, block-selected by the scalar-prefetched shard index (the block
-    index maps read ``sc_ref[0]``) — the stack never leaves its resting
-    place and no per-shard slice is materialized.  ``sc_ref[1]`` is the
-    selected shard's view log2."""
-    _resolve_tile(keys_ref[0], sc_ref[1], None, vk_ref, vv_ref, out_ref,
-                  tile=tile, slots=slots, two_level=False)
+    return _call(functools.partial(_lookup_kernel, two_level=False),
+                 global_depths.astype(jnp.int32), keys, None,
+                 (view_keys, view_vals), select=_by_row, tile=tile,
+                 interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -257,31 +291,11 @@ def stacked_shortcut_lookup(keys, view_keys, view_vals, view_log2s,
     prefetch, so all shards (and all shard *indices*) share one compiled
     specialization, and the flat per-shard lookup path needs no device
     copy of its shard's view."""
-    n = keys.shape[0]
-    pad = (-n) % tile
-    if pad:
-        keys = jnp.pad(keys, ((0, pad),))
-    nt = (n + pad) // tile
-    V, S = view_keys.shape[1:]
     sidx = jnp.asarray(shard, jnp.int32)
     scalars = jnp.stack([sidx, view_log2s.astype(jnp.int32)[sidx]])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,          # (shard, its view log2) in SMEM
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, sc: (0, i)),
-            pl.BlockSpec((1, V, S), lambda i, sc: (sc[0], 0, 0)),
-            pl.BlockSpec((1, V, S), lambda i, sc: (sc[0], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, sc: (0, i)),
-    )
-    kernel = functools.partial(_stacked_select_kernel, tile=tile, slots=S)
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n + pad), jnp.uint32),
-        interpret=resolve_interpret(interpret),
-    )(scalars, keys.astype(jnp.uint32)[None], view_keys, view_vals)
-    return out[0, :n]
+    return _call(_stacked_select_kernel, scalars, keys[None], None,
+                 (view_keys, view_vals), select=lambda b, sc: sc[0],
+                 tile=tile, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -297,44 +311,17 @@ def sharded_routed_lookup(keys, directories, bucket_keys, bucket_vals,
     at ``global_depths``), zero shards resolve through their composed
     views ((N, V, S), slot-indexed at ``view_log2s``; rows past
     ``2**view_log2s[s]`` are pad and never indexed).  Both operand sets
-    ride in VMEM per grid cell — the price of not demoting a mixed
-    batch is one extra resident block pair, which the operand cache
-    (``runtime/operand_cache``) keeps warm anyway.  Returns (N, K)
-    uint32 in the same padded layout as :func:`sharded_eh_lookup`.
+    are VMEM-resident per shard — the price of not demoting a mixed
+    batch is one extra resident block pair.  Returns (N, K) uint32 in
+    the same padded layout as :func:`sharded_eh_lookup`.
     """
-    N, n = keys.shape
     if bucket_keys.shape[-1] != view_keys.shape[-1]:
         raise ValueError(
             f"bucket/view slot widths differ: {bucket_keys.shape[-1]} "
             f"vs {view_keys.shape[-1]}")
-    pad = (-n) % tile
-    if pad:
-        keys = jnp.pad(keys, ((0, 0), (0, pad)))
-    nt = (n + pad) // tile
-    D = directories.shape[1]
-    C, S = bucket_keys.shape[1:]
-    V = view_keys.shape[1]
     scalars = jnp.stack([two_level.astype(jnp.int32),
                          global_depths.astype(jnp.int32),
                          view_log2s.astype(jnp.int32)])        # (3, N)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,          # the packed (3, N) block in SMEM
-        grid=(N, nt),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda s, i, sc: (s, i)),
-            pl.BlockSpec((1, D), lambda s, i, sc: (s, 0)),
-            pl.BlockSpec((1, C, S), lambda s, i, sc: (s, 0, 0)),
-            pl.BlockSpec((1, C, S), lambda s, i, sc: (s, 0, 0)),
-            pl.BlockSpec((1, V, S), lambda s, i, sc: (s, 0, 0)),
-            pl.BlockSpec((1, V, S), lambda s, i, sc: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda s, i, sc: (s, i)),
-    )
-    kernel = functools.partial(_routed_kernel, tile=tile, slots=S)
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, n + pad), jnp.uint32),
-        interpret=resolve_interpret(interpret),
-    )(scalars, keys.astype(jnp.uint32), directories.astype(jnp.int32),
-      bucket_keys, bucket_vals, view_keys, view_vals)
-    return out[:, :n]
+    return _call(_routed_kernel, scalars, keys, directories,
+                 (bucket_keys, bucket_vals, view_keys, view_vals),
+                 select=_by_row, tile=tile, interpret=interpret)

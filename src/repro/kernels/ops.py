@@ -2,13 +2,11 @@
 
 Each op accepts *model-layout* arrays, adapts them to the kernel layouts,
 and dispatches to the kernel.  Execution mode is auto-detected (compiled
-on TPU, interpreted elsewhere — ``kernels/backend.py``); set
-``REPRO_PALLAS_INTERPRET=1``/``0`` to force it process-wide.  ``ref.py``
+on TPU, interpreted elsewhere — ``kernels/backend.py``).  ``ref.py``
 holds the pure-jnp oracles the tests sweep against.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -20,11 +18,6 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ragged_copy import ragged_copy
 from repro.kernels.shortcut_attention import shortcut_attention
-
-_ENV = os.environ.get("REPRO_PALLAS_INTERPRET")
-#: None = auto-detect per backend (kernels/backend.resolve_interpret);
-#: "1"/"0" in the environment force interpret/compiled respectively.
-INTERPRET = None if _ENV is None else _ENV == "1"
 
 
 def mha_forward(q, k, v, *, causal: bool = True,
@@ -41,8 +34,7 @@ def mha_forward(q, k, v, *, causal: bool = True,
     kk = k.transpose(0, 2, 1, 3)
     vk = v.transpose(0, 2, 1, 3)
     o = flash_attention(qk, kk, vk, causal=causal, window=window,
-                        softcap=softcap, bq=bq, bkv=bkv,
-                        interpret=INTERPRET)
+                        softcap=softcap, bq=bq, bkv=bkv)
     return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd)
 
 
@@ -61,7 +53,7 @@ def decode_shortcut(q, view_k, view_v, ctx_len, *,
     kk = view_k.transpose(0, 2, 1, 3)
     vk = view_v.transpose(0, 2, 1, 3)
     o = shortcut_attention(qk, kk, vk, ctx_len, window=window,
-                           softcap=softcap, bs=bs, interpret=INTERPRET)
+                           softcap=softcap, bs=bs)
     return o.reshape(B, H, hd)
 
 
@@ -78,7 +70,7 @@ def decode_paged(q, k_pool, v_pool, block_tables, seq_lens, *,
     kp = k_pool.transpose(0, 2, 1, 3)   # (nblocks, KV, bs, hd)
     vp = v_pool.transpose(0, 2, 1, 3)
     o = paged_attention(qk, kp, vp, block_tables, seq_lens,
-                        softcap=softcap, interpret=INTERPRET)
+                        softcap=softcap)
     return o.reshape(B, H, hd)
 
 
@@ -86,17 +78,16 @@ def eh_lookup_op(keys, st, *, tile: int = 256) -> jax.Array:
     """Traditional fused lookup against an ``EHState``."""
     D = 1 << int(st.max_global_depth)
     return eh_lookup(keys, st.directory[:D], st.bucket_keys,
-                     st.bucket_vals, st.global_depth, tile=tile,
-                     interpret=INTERPRET)
+                     st.bucket_vals, st.global_depth, tile=tile)
 
 
 def shortcut_lookup_op(keys, view_keys, view_vals, global_depth, *,
                        tile: int = 256) -> jax.Array:
     """Shortcut fused lookup against a composed view."""
     return shortcut_lookup(keys, view_keys, view_vals, global_depth,
-                           tile=tile, interpret=INTERPRET)
+                           tile=tile)
 
 
 def remap_rows(view, pool, slots, offsets) -> jax.Array:
     """Maintenance replay: ``view[slots] = pool[offsets]`` (last wins)."""
-    return ragged_copy(view, pool, slots, offsets, interpret=INTERPRET)
+    return ragged_copy(view, pool, slots, offsets)

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import base as cfg_base
 from repro.configs.base import ArchConfig, get
 from repro.data.pipeline import make_batch_specs
@@ -294,6 +295,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="",
                     help="directory for one JSON per cell")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     archs = list(cfg_base.ASSIGNED) if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get
 from repro.kvcache import paged_cache as pc
 from repro.models import model as M
@@ -31,6 +32,7 @@ def main(argv=None) -> int:
                     default="shortcut")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

@@ -19,10 +19,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.checkpoint.checkpointer import Checkpointer, latest_step
 from repro.configs import get
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import sharding as shd
+from repro.launch.mesh import auto_mesh
 from repro.models import model as M
 from repro.optim.adamw import adamw_init
 from repro.optim.schedule import wsd_schedule
@@ -42,7 +44,7 @@ def build_mesh(spec: str):
             "CPU experimentation)")
     names = ("data", "model") if len(dims) == 2 else \
         ("pod", "data", "model")
-    return jax.make_mesh(tuple(dims), names[:len(dims)])
+    return auto_mesh(tuple(dims), names[:len(dims)])
 
 
 def main(argv=None) -> int:
@@ -63,6 +65,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

@@ -185,6 +185,11 @@ class ShortcutMapper:
         self._queue: "queue.SimpleQueue[Request]" = queue.SimpleQueue()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self.name = name
+        # an exception on the mapper thread ends the thread; it is kept
+        # here and re-raised by pump/wait_in_sync/close, so a failed
+        # replay cannot leave reads on the traditional path unnoticed
+        self._error: Optional[Exception] = None
         if async_mapper:
             self._thread = threading.Thread(
                 target=self._loop, daemon=True, name=name)
@@ -294,6 +299,7 @@ class ShortcutMapper:
     def pump(self, max_requests: int = 1 << 30) -> int:
         """Synchronously process pending maintenance (mapper surrogate
         for deterministic tests/benchmarks)."""
+        self._check()
         done = 0
         while done < max_requests:
             batch = self._drain()
@@ -311,12 +317,14 @@ class ShortcutMapper:
         keys = None if keys is None else list(keys)
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
+            self._check()
             if self.in_sync(keys) and self._queue.empty():
                 return True
             if self._thread is None:
                 self.pump()
             else:
                 time.sleep(self.poll_interval / 4)
+        self._check()
         return self.in_sync(keys)
 
     def close(self) -> None:
@@ -324,6 +332,14 @@ class ShortcutMapper:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        self._check()
+
+    def _check(self) -> None:
+        """Re-raise a mapper-thread failure in the calling thread."""
+        if self._error is not None:
+            raise RuntimeError(
+                f"{self.name} thread failed: {self._error!r}"
+            ) from self._error
 
     def _drain(self) -> list:
         out = []
@@ -335,13 +351,16 @@ class ShortcutMapper:
 
     def _loop(self) -> None:
         """The paper's mapper thread: poll at a fixed frequency, replay."""
-        while not self._stop.is_set():
-            batch = self._drain()
-            if batch:
-                with self._replay_mutex:
-                    self._process(batch)
-            else:
-                time.sleep(self.poll_interval)
+        try:
+            while not self._stop.is_set():
+                batch = self._drain()
+                if batch:
+                    with self._replay_mutex:
+                        self._process(batch)
+                else:
+                    time.sleep(self.poll_interval)
+        except Exception as e:      # thread boundary: keep it for _check
+            self._error = e
 
     def _process(self, batch: list) -> None:
         """Replay one drained batch.

@@ -76,9 +76,9 @@ __all__ = ["StackedOperandCache", "OperandCacheStats"]
 
 
 def _backend_can_donate() -> bool:
-    """XLA implements input/output aliasing on accelerators only; CPU
+    """XLA implements input/output aliasing on the TPU only here; CPU
     donation is a warn-and-copy no-op."""
-    return jax.default_backend() in ("tpu", "gpu")
+    return jax.default_backend() == "tpu"
 
 
 @jax.jit

@@ -373,8 +373,17 @@ class MapperGroup:
         return ok
 
     def close(self) -> None:
+        """Close every member, then re-raise the first member's
+        mapper-thread failure (a failing member must not keep the others
+        from closing)."""
+        failures = []
         for m in self.mappers:
-            m.close()
+            try:
+                m.close()
+            except RuntimeError as e:
+                failures.append(e)
+        if failures:
+            raise failures[0]
 
     def __enter__(self):
         return self

@@ -176,7 +176,8 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.optim.schedule import wsd_schedule
     from repro.runtime.train import make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get("internlm2_1_8b").reduced()
     pipe = SyntheticLM(cfg, DataConfig(seq_len=32, global_batch=8))
     p_host = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)

@@ -82,7 +82,8 @@ def test_collectives_inside_loop_multiplied():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_cost import analyze
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         W = jax.ShapeDtypeStruct((8, 256, 256), jnp.float32)
         x = jax.ShapeDtypeStruct((4, 256), jnp.float32)
         def f(w, x):

@@ -148,7 +148,7 @@ class TestDecodeKernels:
 
 
 class TestEHKernels:
-    @pytest.mark.parametrize("n,slots,tile", [(200, 16, 64),
+    @pytest.mark.parametrize("n,slots,tile", [(200, 16, 128),
                                               (1000, 8, 256)])
     def test_lookup_sweep(self, rng, n, slots, tile):
         from repro.core import extendible_hashing as eh
@@ -194,12 +194,12 @@ class TestEHKernels:
             jnp.stack([st.bucket_keys for st in states]),
             jnp.stack([st.bucket_vals for st in states]),
             jnp.asarray([int(st.global_depth) for st in states],
-                        jnp.int32), tile=64)
+                        jnp.int32), tile=128)
         D = states[0].directory.shape[0]
         for s, st in enumerate(states):
             want = eh_lookup(jnp.asarray(padded[s]), st.directory[:D],
                              st.bucket_keys, st.bucket_vals,
-                             st.global_depth, tile=64)
+                             st.global_depth, tile=128)
             np.testing.assert_array_equal(np.asarray(out[s]),
                                           np.asarray(want))
         # shortcut flavour over shape-uniform composed views
@@ -210,12 +210,40 @@ class TestEHKernels:
             jnp.stack([vk for vk, _ in views]),
             jnp.stack([vv for _, vv in views]),
             jnp.asarray([int(st.global_depth) for st in states],
-                        jnp.int32), tile=64)
+                        jnp.int32), tile=128)
         for s, st in enumerate(states):
             want = shortcut_lookup(jnp.asarray(padded[s]), *views[s],
-                                   st.global_depth, tile=64)
+                                   st.global_depth, tile=128)
             np.testing.assert_array_equal(np.asarray(out_sc[s]),
                                           np.asarray(want))
+
+    @pytest.mark.parametrize("slots", [8, 64])
+    def test_rank_mask_probe_matches_gather_probe(self, rng, slots):
+        """``hashing.probe_rows`` (the kernels' rank-mask probe) answers
+        as the XLA path's gather probe does on adversarial rows: ghost
+        hits past an EMPTY, repeated keys, full rows and misses."""
+        from repro.core import hashing
+        T = 512
+        keys = rng.integers(0, 6, T, dtype=np.uint32)   # tiny alphabet
+        row_k = rng.integers(0, 6, (T, slots), dtype=np.uint32)
+        row_k[rng.random((T, slots)) < 0.15] = hashing.EMPTY_SENTINEL
+        row_v = rng.integers(0, 2**32, (T, slots), dtype=np.uint32)
+
+        def gather_probe(rk, rv, key):
+            pos = hashing.probe_positions(key, slots)
+            found, j = hashing.probe_hit(rk[pos], key)
+            return found, jnp.where(found, rv[pos[j]], jnp.uint32(0))
+
+        want_f, want_v = jax.vmap(gather_probe)(
+            jnp.asarray(row_k), jnp.asarray(row_v), jnp.asarray(keys))
+        found, value = hashing.probe_rows(
+            jnp.asarray(row_k), jnp.asarray(row_v),
+            jnp.asarray(keys)[:, None])
+        np.testing.assert_array_equal(np.asarray(found[:, 0]),
+                                      np.asarray(want_f))
+        np.testing.assert_array_equal(np.asarray(value[:, 0]),
+                                      np.asarray(want_v))
+        assert 0 < int(want_f.sum()) < T       # both outcomes exercised
 
     def test_shortcut_kernel_matches_traditional(self, rng):
         from repro.core import extendible_hashing as eh

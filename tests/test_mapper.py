@@ -11,6 +11,7 @@ from repro.kvcache.shortcut_cache import ShortcutKVManager
 from repro.runtime.mapper import (CREATE, GLOBAL_VIEW, FanInRouting,
                                   FragmentationRouting, HysteresisRouting,
                                   Request, ShortcutMapper)
+from repro.runtime.shard_group import MapperGroup
 
 from conftest import unique_keys
 
@@ -165,6 +166,41 @@ class TestAsyncEquivalence:
         np.testing.assert_array_equal(results["pump"][1],
                                       results["async"][1])
         assert results["pump"][2] == results["async"][2]
+
+
+class FailingClient(ToyClient):
+    def _replay_update(self, snap, requests):
+        raise ValueError("replay broke")
+
+
+class TestThreadFailure:
+    def test_replay_exception_reraised_in_caller(self):
+        """A replay that raises on the mapper thread ends that thread;
+        pump, wait_in_sync and close re-raise it in the caller instead
+        of leaving reads on the traditional path without a sign."""
+        t = FailingClient(async_mapper=True, poll_interval=0.001)
+        t.put("a", 1)
+        with pytest.raises(RuntimeError, match="replay broke") as err:
+            t.mapper.wait_in_sync([GLOBAL_VIEW], timeout=30.0)
+        assert isinstance(err.value.__cause__, ValueError)
+        assert not t.mapper.in_sync([GLOBAL_VIEW])
+        with pytest.raises(RuntimeError, match="replay broke"):
+            t.mapper.pump()
+        with pytest.raises(RuntimeError, match="replay broke"):
+            t.mapper.close()
+
+    def test_group_close_closes_every_member_then_raises(self):
+        bad = FailingClient(async_mapper=True, poll_interval=0.001)
+        good = ToyClient(async_mapper=True, poll_interval=0.001)
+        group = MapperGroup([bad.mapper, good.mapper])
+        bad.put("a", 1)
+        good.put("b", 2)
+        with pytest.raises(RuntimeError, match="replay broke"):
+            group.wait_in_sync(timeout=30.0)
+        assert good.mapper.wait_in_sync(timeout=30.0)
+        with pytest.raises(RuntimeError, match="replay broke"):
+            group.close()
+        assert good.mapper._thread is None
 
 
 class TestRoutingPolicies:

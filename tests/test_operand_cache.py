@@ -366,14 +366,14 @@ class TestRoutedKernelParity:
     def test_matches_static_kernels(self, rng, flags):
         o = _stacked_shards(rng, 4)
         ref = kmod.sharded_eh_lookup(o["keys"], o["dirs"], o["bks"],
-                                     o["bvs"], o["gds"], tile=64)
+                                     o["bvs"], o["gds"], tile=128)
         via_view = kmod.sharded_shortcut_lookup(o["keys"], o["vks"],
-                                                o["vvs"], o["vls"], tile=64)
+                                                o["vvs"], o["vls"], tile=128)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(via_view))
         got = kmod.sharded_routed_lookup(
             o["keys"], o["dirs"], o["bks"], o["bvs"], o["gds"],
             o["vks"], o["vvs"], o["vls"],
-            jnp.asarray(flags, jnp.int32), tile=64)
+            jnp.asarray(flags, jnp.int32), tile=128)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
     def test_stacked_single_shard_select_matches_flat(self, rng):
@@ -390,7 +390,7 @@ class TestRoutedKernelParity:
             ref = eh.shortcut_lookup_many(
                 o["vks"][s], o["vvs"][s], int(o["vls"][s]), keys)
             got = kmod.stacked_shortcut_lookup(
-                keys, o["vks"], o["vvs"], o["vls"], s, tile=64)
+                keys, o["vks"], o["vvs"], o["vls"], s, tile=128)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
     def test_slot_width_mismatch_rejected(self, rng):
@@ -399,7 +399,7 @@ class TestRoutedKernelParity:
             kmod.sharded_routed_lookup(
                 o["keys"], o["dirs"], o["bks"], o["bvs"], o["gds"],
                 o["vks"][:, :, :4], o["vvs"][:, :, :4], o["vls"],
-                jnp.zeros(2, jnp.int32), tile=64)
+                jnp.zeros(2, jnp.int32), tile=128)
 
 
 # ---------------------------------------------------------------------------

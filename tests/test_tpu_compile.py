@@ -1,0 +1,83 @@
+"""Compile rehearsals of the lookup kernels for a TPU v5e, without one.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached: what Mosaic refuses here (unaligned blocks,
+unlowerable primitives, too much VMEM or SMEM) it would refuse on the
+chip.  Interpret mode, which every other kernel test uses, cannot show
+any of that.  Shapes are ``chip_smoke.py``'s: 64-slot buckets,
+directories and views of 2^14 rows, 2048-bucket pools, 65,536-key
+batches, for the flat index (N=1) and for 16 shards (a 65,536-key batch
+pads to 16,384 keys per shard).
+
+The topology is described inside a module fixture, never while a module
+is imported: only one process at a time may load the TPU library, and
+the test runner's workers all import this file.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import eh_lookup as kernels
+
+DEPTH, SLOTS, CAPACITY = 14, 64, 2048
+HBM_BYTES = 16 * 2**30                 # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _args(sharding, entry: str, n: int):
+    D = V = 1 << DEPTH
+    batch = 1 << 16
+    keys_per_shard = batch if n == 1 else 1 << 14
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    keys = s((n, keys_per_shard), jnp.uint32)
+    trad = (s((n, D), jnp.int32), s((n, CAPACITY, SLOTS), jnp.uint32),
+            s((n, CAPACITY, SLOTS), jnp.uint32), s((n,), jnp.int32))
+    view = (s((n, V, SLOTS), jnp.uint32), s((n, V, SLOTS), jnp.uint32),
+            s((n,), jnp.int32))
+    return {
+        "sharded_eh_lookup": (keys, *trad),
+        "sharded_shortcut_lookup": (keys, *view),
+        "sharded_routed_lookup": (keys, *trad, *view, s((n,), jnp.int32)),
+        "stacked_shortcut_lookup": (s((4096,), jnp.uint32), *view,
+                                    s((), jnp.int32)),
+    }[entry]
+
+
+@pytest.mark.parametrize("n", [1, 16])
+@pytest.mark.parametrize("entry", ["sharded_eh_lookup",
+                                   "sharded_shortcut_lookup",
+                                   "sharded_routed_lookup",
+                                   "stacked_shortcut_lookup"])
+def test_lookup_compiles_for_v5e(one_chip, entry, n):
+    fn = functools.partial(getattr(kernels, entry), interpret=False)
+    compiled = jax.jit(fn).lower(*_args(one_chip, entry, n)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, used
