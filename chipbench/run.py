@@ -1,0 +1,58 @@
+#!/usr/bin/env python3
+"""Run one benchmark cell on the chip and print its result line.
+
+  python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s>
+                           --trace <0|1>
+
+The last line of standard output is one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
+or with ``--trace 1`` its per-layer metrics), ``device``, with
+``--trace 1`` a ``breakdown``, and last the ``checks``: each number
+compared with the plain reference beside its limit.  The checks are also
+the last lines of standard error.  Exits 2, printing no result, when JAX
+finds no TPU or fewer chips than the cell asks for.
+
+``--fault`` plants the control or a fault (``chipbench/faults.py``) for
+showing that ``correct`` catches it; the benchmark's own runs never set
+it.
+"""
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--fault", default=None,
+                    choices=("control", "state_unchanged", "half_batch",
+                             "altered_answer"))
+    args = ap.parse_args(argv)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+    from chipbench import harness
+    harness.configure_jax()
+    try:
+        result = harness.run_cell(args.workload, args.seed, args.seconds,
+                                  bool(args.trace), t_start=T_START,
+                                  fault=args.fault)
+    except harness.NoChip as e:
+        print(f"chipbench: {e}", file=sys.stderr)
+        return 2
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
