@@ -51,6 +51,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import extendible_hashing as eh
 from repro.core.hashing import HASH_C1
@@ -198,7 +199,11 @@ class ShardedShortcutEH:
         operands come from :class:`StackedOperandCache` keyed by the
         shards' publish epochs, so a batch against an unchanged index
         uploads nothing and a replay-churned batch re-uploads only the
-        dirty shards' slices.  Returns values in input order."""
+        dirty shards' slices.  Returns values in input order.
+
+        Profiler spans, one per step: ``lookup.bucketize``,
+        ``lookup.gate``, ``lookup.operands``, ``lookup.dispatch``,
+        ``lookup.wait`` (results to the host) and ``lookup.scatter``."""
         from repro.kernels.eh_lookup import (sharded_eh_lookup,
                                              sharded_routed_lookup,
                                              sharded_shortcut_lookup)
@@ -207,12 +212,13 @@ class ShardedShortcutEH:
             # no padding, no operand refresh, no dispatch, no route
             # counters — an empty batch must not touch the device
             return jnp.zeros((0,), jnp.uint32)
-        sid = self.shard_of(keys)
-        order, counts, starts = shard_order(sid, self.num_shards)
-        cap = pad_batch(int(counts.max()))
-        padded, counts, order, rank = partition_by_shard(
-            keys, sid, self.num_shards, cap,
-            order=order, counts=counts, starts=starts)
+        with TraceAnnotation("lookup.bucketize"):
+            sid = self.shard_of(keys)
+            order, counts, starts = shard_order(sid, self.num_shards)
+            cap = pad_batch(int(counts.max()))
+            padded, counts, order, rank = partition_by_shard(
+                keys, sid, self.num_shards, cap,
+                order=order, counts=counts, starts=starts)
         # Gate every shard FIRST (each policy decides exactly once — no
         # short-circuit), then read publish epochs/flags: replays
         # publish into the stack BEFORE bumping view_epoch and BEFORE
@@ -222,37 +228,43 @@ class ShardedShortcutEH:
         # family stays pull-mode: built lazily here from the per-shard
         # state snapshots (read AFTER the epochs, so an epoch can only
         # under-describe its snapshot), kept warm by insert's push.
-        gates = [s.mapper.gate(s.avg_fan_in(), [GLOBAL_VIEW])
-                 for s in self.shards]
-        view_epochs = [s.view_epoch for s in self.shards]
-        state_epochs = [s.state_epoch for s in self.shards]
-        states = [s.state for s in self.shards]
-        pub = self.operands.published("eh_view")
-        shortcut_ok = [g and pub is not None and pub[i]
-                       for i, g in enumerate(gates)]
-        involved = [int(s) for s in np.nonzero(counts)[0]]
-        for s in involved:
-            self.group.count_route(shortcut_ok[s], shard=s)
-        n_sc = sum(1 for s in involved if shortcut_ok[s])
-        keys_dev = jnp.asarray(padded)
-        if n_sc:
-            view_ops = self.operands.get("eh_view", view_epochs)
-        if n_sc < len(involved):
-            trad_ops = self.operands.get(
-                "eh_trad", state_epochs, _trad_parts(states))
-        if n_sc == len(involved):
-            res = sharded_shortcut_lookup(keys_dev, *view_ops, tile=tile)
-        elif n_sc == 0:
-            res = sharded_eh_lookup(keys_dev, *trad_ops, tile=tile)
-        else:
-            flags = jnp.asarray(
-                [0 if ok else 1 for ok in shortcut_ok], jnp.int32)
-            res = sharded_routed_lookup(keys_dev, *trad_ops, *view_ops,
-                                        flags, tile=tile)
-        res = np.asarray(res)
-        out = np.empty(keys.size, np.uint32)
-        out[order] = res[sid[order], rank]
-        return jnp.asarray(out)
+        with TraceAnnotation("lookup.gate"):
+            gates = [s.mapper.gate(s.avg_fan_in(), [GLOBAL_VIEW])
+                     for s in self.shards]
+            view_epochs = [s.view_epoch for s in self.shards]
+            state_epochs = [s.state_epoch for s in self.shards]
+            states = [s.state for s in self.shards]
+            pub = self.operands.published("eh_view")
+            shortcut_ok = [g and pub is not None and pub[i]
+                           for i, g in enumerate(gates)]
+            involved = [int(s) for s in np.nonzero(counts)[0]]
+            for s in involved:
+                self.group.count_route(shortcut_ok[s], shard=s)
+            n_sc = sum(1 for s in involved if shortcut_ok[s])
+        with TraceAnnotation("lookup.operands"):
+            if n_sc:
+                view_ops = self.operands.get("eh_view", view_epochs)
+            if n_sc < len(involved):
+                trad_ops = self.operands.get(
+                    "eh_trad", state_epochs, _trad_parts(states))
+        with TraceAnnotation("lookup.dispatch"):
+            keys_dev = jnp.asarray(padded)
+            if n_sc == len(involved):
+                res = sharded_shortcut_lookup(keys_dev, *view_ops,
+                                              tile=tile)
+            elif n_sc == 0:
+                res = sharded_eh_lookup(keys_dev, *trad_ops, tile=tile)
+            else:
+                flags = jnp.asarray(
+                    [0 if ok else 1 for ok in shortcut_ok], jnp.int32)
+                res = sharded_routed_lookup(keys_dev, *trad_ops,
+                                            *view_ops, flags, tile=tile)
+        with TraceAnnotation("lookup.wait"):
+            res = np.asarray(res)
+        with TraceAnnotation("lookup.scatter"):
+            out = np.empty(keys.size, np.uint32)
+            out[order] = res[sid[order], rank]
+            return jnp.asarray(out)
 
     # -- aggregated bookkeeping ----------------------------------------------
 

@@ -30,11 +30,13 @@ The asynchronous, version-gated architecture is unchanged.
 """
 from __future__ import annotations
 
+from contextlib import contextmanager
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import extendible_hashing as eh
 from repro.core import rewiring
@@ -57,6 +59,17 @@ def _pad_chunk(n: int) -> int:
         if n <= c:
             return c
     return _CHUNK_SIZES[-1]
+
+
+@contextmanager
+def _held(lock, span: str):
+    """Hold ``lock``, with the wait for it as the profiler span ``span``."""
+    with TraceAnnotation(span):
+        lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 class ShortcutEH:
@@ -212,33 +225,43 @@ class ShortcutEH:
 
     def insert(self, keys, values) -> None:
         """Synchronous insert into the traditional index + enqueue
-        maintenance (the paper's main-thread behaviour)."""
+        maintenance (the paper's main-thread behaviour).
+
+        Profiler spans: ``insert.scan`` (the scan, waited for by the
+        depth reads), ``insert.lock`` (the wait for the mapper's lock),
+        ``insert.publish``, ``insert.touched``, ``insert.submit``."""
         keys = jnp.asarray(keys, jnp.uint32)
         values = jnp.asarray(values, jnp.uint32)
-        old_g = int(self.state.global_depth)
-        with self.mapper.lock:
-            self.state = eh.eh_insert_many(self.state, keys, values)
-            new_g = int(self.state.global_depth)
-            versions = self.mapper.record([GLOBAL_VIEW])
-            if self._cache is not None:
-                # keep the stacked traditional family warm at publish
-                # (write) time — but only once a lookup actually built
-                # it; a shortcut-routed steady state never pays for (or
-                # holds) the traditional stack at all
-                st = self.state
-                self._cache.publish_if_present(
-                    self._tfam, self._shard,
-                    lambda: (st.directory, st.bucket_keys,
-                             st.bucket_vals, st.global_depth),
-                    epoch=self.mapper.trad_epoch)
+        with TraceAnnotation("insert.scan"):
+            old_g = int(self.state.global_depth)
+            with _held(self.mapper.lock, "insert.lock"):
+                self.state = eh.eh_insert_many(self.state, keys, values)
+                new_g = int(self.state.global_depth)
+                versions = self.mapper.record([GLOBAL_VIEW])
+                if self._cache is not None:
+                    # keep the stacked traditional family warm at
+                    # publish (write) time — but only once a lookup
+                    # actually built it; a shortcut-routed steady state
+                    # never pays for (or holds) the traditional stack
+                    st = self.state
+                    with TraceAnnotation("insert.publish"):
+                        self._cache.publish_if_present(
+                            self._tfam, self._shard,
+                            lambda: (st.directory, st.bucket_keys,
+                                     st.bucket_vals, st.global_depth),
+                            epoch=self.mapper.trad_epoch)
         if new_g != old_g:
             # doubling: the runtime pops outdated updates before the create
-            self.mapper.submit_create([GLOBAL_VIEW], versions)
+            with TraceAnnotation("insert.submit", version=versions[0]):
+                self.mapper.submit_create([GLOBAL_VIEW], versions)
         else:
-            slots = eh.dir_slot(eh.hash_dir(keys), self.state.global_depth)
-            touched = np.unique(np.asarray(self.state.directory[slots]))
-            self.mapper.submit_update([GLOBAL_VIEW], versions,
-                                      payload=touched)
+            with TraceAnnotation("insert.touched"):
+                slots = eh.dir_slot(eh.hash_dir(keys),
+                                    self.state.global_depth)
+                touched = np.unique(np.asarray(self.state.directory[slots]))
+            with TraceAnnotation("insert.submit", version=versions[0]):
+                self.mapper.submit_update([GLOBAL_VIEW], versions,
+                                          payload=touched)
 
     def lookup(self, keys) -> jax.Array:
         """Route through the shortcut when in sync and fan-in permits."""
@@ -339,11 +362,13 @@ class ShortcutEH:
             self._replay_create(st, requests)
             return
         vk, vv, vlog2 = view
-        touched = np.unique(np.concatenate([r.payload for r in requests]))
-        g = int(st.global_depth)
-        dir_np = np.asarray(st.directory[: 1 << g])
-        stale = np.isin(dir_np, touched)
-        slots = np.nonzero(stale)[0].astype(np.int32)
+        with TraceAnnotation("mapper.discover"):
+            touched = np.unique(np.concatenate([r.payload
+                                                for r in requests]))
+            g = int(st.global_depth)
+            dir_np = np.asarray(st.directory[: 1 << g])
+            stale = np.isin(dir_np, touched)
+            slots = np.nonzero(stale)[0].astype(np.int32)
         if slots.size == 0:
             if self._cache is not None:
                 # no stale slots, but the reader is still owed an epoch:
@@ -353,13 +378,16 @@ class ShortcutEH:
                 self._cache.touch(self._vfam, self._shard,
                                   epoch=self.mapper.next_view_epoch)
             return
-        n = _pad_chunk(slots.size)
-        pad = n - slots.size
-        slots_p = np.concatenate([slots, np.zeros(pad, np.int32)])
-        offsets_p = dir_np[slots_p].astype(np.int32)
-        vk = rewiring.remap_slots(vk, st.bucket_keys, slots_p, offsets_p)
-        vv = rewiring.remap_slots(vv, st.bucket_vals, slots_p, offsets_p)
-        self._publish_view(vk, vv, vlog2)
+        with TraceAnnotation("mapper.remap"):
+            n = _pad_chunk(slots.size)
+            pad = n - slots.size
+            slots_p = np.concatenate([slots, np.zeros(pad, np.int32)])
+            offsets_p = dir_np[slots_p].astype(np.int32)
+            vk = rewiring.remap_slots(vk, st.bucket_keys, slots_p,
+                                      offsets_p)
+            vv = rewiring.remap_slots(vv, st.bucket_vals, slots_p,
+                                      offsets_p)
+            self._publish_view(vk, vv, vlog2)
         self.mapper.stats.slots_remapped += int(slots.size)
 
     def __enter__(self):

@@ -41,8 +41,10 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Hashable, Iterable, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 #: View key for clients that maintain a single, global shortcut view.
 GLOBAL_VIEW: Hashable = "__global__"
@@ -56,10 +58,18 @@ class Request:
     """One maintenance request in the FIFO.
 
     ``versions`` maps each view key the request touches to the
-    ``trad_version`` that replaying it brings the shortcut to."""
+    ``trad_version`` that replaying it brings the shortcut to.
+    ``submitted`` is its ``time.perf_counter`` stamp at submit;
+    ``absorbed`` holds the stamps of the requests it made redundant at
+    enqueue time, so their lag is counted when it publishes."""
     kind: str                      # CREATE | UPDATE
     versions: dict                 # view key -> target trad_version
     payload: Any = None            # client data (touched buckets, rows, ...)
+    submitted: float = field(default_factory=time.perf_counter)
+    absorbed: list = field(default_factory=list)
+
+    def stamps(self) -> list:
+        return [self.submitted, *self.absorbed]
 
 
 @dataclass
@@ -70,6 +80,10 @@ class MaintenanceStats:
     slots_remapped: int = 0        # client-reported rewired slots/rows
     replay_seconds: float = 0.0
     populate_seconds: float = 0.0
+    # submit-to-publish time summed over requests, collapsed ones
+    # included, and the number of requests it sums
+    lag_seconds: float = 0.0
+    lag_requests: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -261,8 +275,11 @@ class ShortcutMapper:
         :meth:`_process` catches any that race past this."""
         req = Request(CREATE, dict(zip(keys, versions)), payload)
         pending = self._drain()
-        kept = [r for r in pending if not _subsumed(r, req.versions)]
-        self.stats.collapsed += len(pending) - len(kept)
+        kept, dropped = [], []
+        for r in pending:
+            (dropped if _subsumed(r, req.versions) else kept).append(r)
+        self.stats.collapsed += len(dropped)
+        req.absorbed = [t for r in dropped for t in r.stamps()]
         for r in kept:
             self._queue.put(r)
         self._queue.put(req)
@@ -376,9 +393,15 @@ class ShortcutMapper:
            the lookup path never patches);
         3. eagerly populate the view arrays (§3.1);
         4. bump ``view_epoch`` to the epoch the replays published at,
-           then publish ``sc_version`` monotonically.
+           then publish ``sc_version`` monotonically, and count each
+           request's lag from submit to here.
+
+        The profiler spans carry ``version``, the highest trad version
+        the batch publishes, which names the insert that caused it.
         """
-        with self.lock:
+        version = max((v for r in batch for v in r.versions.values()),
+                      default=-1)
+        with TraceAnnotation("mapper.snapshot", version=version), self.lock:
             snap = self._snapshot()
 
         last_create: dict = {}
@@ -394,22 +417,24 @@ class ShortcutMapper:
             kept.append(r)
 
         t0 = time.perf_counter()
-        i = 0
-        while i < len(kept):
-            j = i
-            while j < len(kept) and kept[j].kind == kept[i].kind:
-                j += 1
-            run = kept[i:j]
-            if kept[i].kind == CREATE:
-                self._replay_create(snap, run)
-                self.stats.creates += 1
-            else:
-                self._replay_update(snap, run)
-                self.stats.updates += 1
-            i = j
+        with TraceAnnotation("mapper.replay", version=version):
+            i = 0
+            while i < len(kept):
+                j = i
+                while j < len(kept) and kept[j].kind == kept[i].kind:
+                    j += 1
+                run = kept[i:j]
+                if kept[i].kind == CREATE:
+                    self._replay_create(snap, run)
+                    self.stats.creates += 1
+                else:
+                    self._replay_update(snap, run)
+                    self.stats.updates += 1
+                i = j
         t1 = time.perf_counter()
-        for a in self._view_arrays():
-            a.block_until_ready()
+        with TraceAnnotation("mapper.populate", version=version):
+            for a in self._view_arrays():
+                a.block_until_ready()
         t2 = time.perf_counter()
         self.stats.replay_seconds += t1 - t0
         self.stats.populate_seconds += t2 - t1
@@ -424,6 +449,11 @@ class ShortcutMapper:
         for r in batch:
             for k, v in r.versions.items():
                 self._sc[k] = max(self._sc.get(k, -1), v)
+        now = time.perf_counter()
+        for r in batch:
+            stamps = r.stamps()
+            self.stats.lag_seconds += sum(now - t for t in stamps)
+            self.stats.lag_requests += len(stamps)
 
     def __enter__(self):
         return self

@@ -112,6 +112,7 @@ class OperandCacheStats:
     lookup_refreshes: int = 0    # slices patched on the lookup path (pull mode)
     rebuilds: int = 0            # full (re)stacks: first build / shape growth
     resident: Dict[str, int] = field(default_factory=dict)  # bytes per family
+    publish_bytes: int = 0       # bytes of the stacks publish() wrote
 
     @property
     def slice_refreshes(self) -> int:
@@ -121,7 +122,7 @@ class OperandCacheStats:
     def snapshot(self) -> "OperandCacheStats":
         return OperandCacheStats(self.hits, self.publish_refreshes,
                                  self.lookup_refreshes, self.rebuilds,
-                                 dict(self.resident))
+                                 dict(self.resident), self.publish_bytes)
 
 
 @dataclass
@@ -295,6 +296,8 @@ class StackedOperandCache:
             ent.published[shard] = True
             ent.epochs[shard] = max(ent.epochs[shard], int(epoch))
             self.stats.publish_refreshes += 1
+            # without donation each refresh writes a whole new stack
+            self.stats.publish_bytes += sum(int(a.nbytes) for a in arrays)
 
     def publish_if_present(self, family: str, shard: int,
                            parts: Callable[[], Tuple[jax.Array, ...]], *,

@@ -1,6 +1,8 @@
 """The generic shortcut-maintenance runtime (``runtime/mapper.py``):
 version monotonicity, create-collapses-updates batching, async/pump
 equivalence, routing policies, and EH<->KV client parity."""
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,9 +10,9 @@ import pytest
 from repro.core.shortcut_eh import ShortcutEH
 from repro.kvcache import paged_cache as pc
 from repro.kvcache.shortcut_cache import ShortcutKVManager
-from repro.runtime.mapper import (CREATE, GLOBAL_VIEW, FanInRouting,
-                                  FragmentationRouting, HysteresisRouting,
-                                  Request, ShortcutMapper)
+from repro.runtime.mapper import (CREATE, GLOBAL_VIEW, UPDATE,
+                                  FanInRouting, FragmentationRouting,
+                                  HysteresisRouting, Request, ShortcutMapper)
 from repro.runtime.shard_group import MapperGroup
 
 from conftest import unique_keys
@@ -140,6 +142,69 @@ class TestCollapse:
         t.mapper.pump()
         assert t.update_keys == ["s1"]
         assert t.mapper.in_sync(["seq0", "seq1"])
+
+
+class TestLag:
+    """``lag_seconds`` / ``lag_requests``: submit-to-publish time of every
+    request, collapsed ones included."""
+
+    @staticmethod
+    def _old_update(t, key, val, age_s):
+        """Enqueue an update as if it had been submitted ``age_s`` ago."""
+        with t.mapper.lock:
+            t.data[key] = val
+            (v,) = t.mapper.record([GLOBAL_VIEW])
+        t.mapper._queue.put(Request(UPDATE, {GLOBAL_VIEW: v}, (key, val),
+                                    submitted=time.perf_counter() - age_s))
+
+    def test_one_update_is_one_lagged_request(self):
+        t = ToyClient()
+        t.put("a", 1)
+        assert t.mapper.stats.lag_requests == 0
+        t.mapper.pump()
+        assert t.mapper.stats.lag_requests == 1
+        assert t.mapper.stats.lag_seconds > 0
+
+    def test_lag_runs_from_submit_to_publish(self):
+        t = ToyClient()
+        self._old_update(t, "a", 1, age_s=5.0)
+        t.mapper.pump()
+        assert t.mapper.stats.lag_requests == 1
+        assert 5.0 <= t.mapper.stats.lag_seconds < 60.0
+
+    def test_collapsed_update_counts_when_its_create_publishes(self):
+        t = ToyClient()
+        self._old_update(t, "a", 1, age_s=5.0)
+        t.put("b", 2, kind="create")      # pops the update at enqueue
+        assert t.mapper.stats.collapsed == 1
+        assert t.mapper.stats.lag_requests == 0
+        t.mapper.pump()
+        assert t.update_keys == []
+        assert t.mapper.stats.lag_requests == 2
+        assert t.mapper.stats.lag_seconds >= 5.0
+
+    def test_batch_side_collapse_is_counted(self):
+        t = ToyClient()
+        with t.mapper.lock:
+            (v1,) = t.mapper.record([GLOBAL_VIEW])
+            (v2,) = t.mapper.record([GLOBAL_VIEW])
+        t.mapper._queue.put(Request(CREATE, {GLOBAL_VIEW: v2}))
+        t.mapper.submit_update([GLOBAL_VIEW], [v1], payload=("x", 1))
+        t.mapper.pump()
+        assert t.mapper.stats.collapsed == 1
+        assert t.mapper.stats.lag_requests == 2
+
+    def test_group_stats_sum_both_counters(self):
+        a, b = ToyClient(), ToyClient()
+        group = MapperGroup([a.mapper, b.mapper])
+        a.put("x", 1)
+        b.put("y", 2)
+        b.put("z", 3)
+        group.pump()
+        agg = group.stats
+        assert agg.lag_requests == 3
+        assert agg.lag_seconds == pytest.approx(
+            a.mapper.stats.lag_seconds + b.mapper.stats.lag_seconds)
 
 
 class TestAsyncEquivalence:

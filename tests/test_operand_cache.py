@@ -249,6 +249,26 @@ class TestPublishPath:
         np.testing.assert_array_equal(
             np.asarray(cache.slice_of("v", 1)[0]), 2)
 
+    def test_publish_bytes_count_each_stack_a_publish_writes(self):
+        cache = StackedOperandCache(3)
+        parts = (jnp.ones((4, 8), jnp.uint32), jnp.ones((4,), jnp.int32))
+        cache.publish("v", 1, parts, epoch=1)
+        stack_bytes = sum(a.nbytes for a in cache.handle("v"))
+        assert stack_bytes == 3 * (4 * 8 * 4 + 4 * 4)
+        assert cache.stats.publish_bytes == stack_bytes
+        cache.publish("v", 2, parts, epoch=1)
+        assert cache.stats.publish_bytes == 2 * stack_bytes
+        # a lookup-path hit and an empty replay's touch write nothing
+        cache.get("v", [0, 1, 1])
+        cache.touch("v", 0, epoch=2)
+        assert cache.stats.hits == 1
+        assert cache.stats.publish_bytes == 2 * stack_bytes
+        # publish_if_present goes through publish and is counted there
+        cache.publish_if_present("v", 0, lambda: parts, epoch=3)
+        cache.publish_if_present("absent", 0, lambda: parts, epoch=1)
+        assert cache.stats.publish_bytes == 3 * stack_bytes
+        assert cache.stats.snapshot().publish_bytes == 3 * stack_bytes
+
     def test_publish_if_present_only_warms_existing(self):
         cache = StackedOperandCache(2)
         calls = []
