@@ -65,7 +65,8 @@ def run(scale: float = 1.0 / 100):
                               bucket_slots=16))
     # EH
     eh_capacity = max(64, int(n / (bucket_slots * 0.3)) * 4)
-    insert_curve("EH", None, eh.eh_insert_many, eh.eh_lookup_many,
+    insert_curve("EH", None, lambda s, k, v: eh.eh_insert_many(s, k, v)[0],
+                 eh.eh_lookup_many,
                  eh.eh_create(max_global_depth=16,
                               bucket_slots=bucket_slots,
                               capacity=eh_capacity))

@@ -24,8 +24,9 @@ probe primitives are shared with the kernels and baselines via
 ``core/hashing.py`` (``hash_dir``/``hash_bucket``/``dir_slot`` are
 re-exported here for backwards compatibility).
 
-All mutating ops return a new state (functional); batched insertion is a
-``lax.scan``, batched lookup a ``vmap``.
+All mutating ops return a new state (functional); batched insertion
+overwrites present keys in one vectorised pass and loops over the absent
+ones, batched lookup is a ``vmap``.
 """
 from __future__ import annotations
 
@@ -218,15 +219,79 @@ def eh_insert(st: EHState, key: jax.Array, value: jax.Array) -> EHState:
     )
 
 
+# Keys classified per program: the (tile, bucket_slots) rows gathered for
+# one tile stay near 8 MiB at 512 slots, whatever the batch length.
+CLASSIFY_TILE = 4096
+
+
+def _classify(st: EHState, keys: jax.Array):
+    """Bucket, slot and presence of each key in ``st``, in tiles of at
+    most ``CLASSIFY_TILE`` keys.  A key counts as present only where
+    ``bucket_find`` would find it (before the first EMPTY of its probe
+    sequence); its slot is then the one ``bucket_put`` would overwrite.
+    Also returns each key's ``ahead``: the number of absent keys before
+    it in the batch (a tile at a time, since the TPU compiler takes
+    seconds over a prefix sum at a bulk load's length)."""
+    n = keys.shape[0]
+    tile = min(n, CLASSIFY_TILE)
+    tiles = -(-n // tile)
+    padded = jnp.pad(keys, (0, tiles * tile - n)).reshape(tiles, tile)
+
+    def one(seen, tk):
+        b = st.directory[dir_slot(hash_dir(tk), st.global_depth)]
+        found, slot = hashing.probe_rows_slot(st.bucket_keys[b],
+                                              tk[:, None])
+        absent = 1 - found[:, 0].astype(jnp.int32)
+        ahead = seen + jnp.cumsum(absent) - absent
+        return seen + jnp.sum(absent), (b, slot[:, 0], found[:, 0], ahead)
+
+    _, parts = jax.lax.scan(one, jnp.int32(0), padded)
+    return tuple(x.reshape(-1)[:n] for x in parts)
+
+
 @jax.jit
-def eh_insert_many(st: EHState, keys: jax.Array,
-                   values: jax.Array) -> EHState:
-    """Sequential batch insert (splits serialize inserts by nature)."""
-    def body(s, kv):
-        return eh_insert(s, kv[0], kv[1]), None
-    st, _ = jax.lax.scan(body, st, jnp.stack(
-        [keys.astype(jnp.uint32), values.astype(jnp.uint32)], axis=1))
-    return st
+def eh_insert_many(st: EHState, keys: jax.Array, values: jax.Array):
+    """Batch upsert, equal to ``eh_insert`` of each pair in batch order.
+
+    Keys present in ``st`` can never split their bucket, so they are
+    overwritten in place in one vectorised pass, the last occurrence of
+    each winning.  Keys absent from ``st`` then run through ``eh_insert``
+    one at a time, in batch order (splits serialize them by nature); a
+    split moves the overwritten values along with their rows.  Returns
+    ``(state, fresh)``: ``fresh`` is the number of absent keys, the
+    sequential loop's trip count."""
+    keys = keys.astype(jnp.uint32)
+    values = values.astype(jnp.uint32)
+    n = keys.shape[0]
+    if n == 0:
+        return st, jnp.zeros((), jnp.int32)
+    b, slot, present, ahead = _classify(st, keys)
+    idx = jnp.arange(n, dtype=jnp.int32)
+
+    # the last occurrence of each present key is the largest batch index
+    # aimed at its slot: a max does not depend on the order in which
+    # duplicate scatter indices apply, which XLA leaves open (a sort by
+    # key would do too, but takes the TPU compiler half a minute at a
+    # bulk load's length)
+    last = jnp.full(st.bucket_vals.shape, -1, jnp.int32).at[
+        jnp.where(present, b, st.capacity), slot].max(idx, mode="drop")
+    won = present & (last[b, slot] == idx)
+    st = st._replace(bucket_vals=st.bucket_vals.at[
+        jnp.where(won, b, st.capacity), slot].set(values, mode="drop"))
+
+    # absent keys, compacted to the front in batch order
+    fresh = n - jnp.sum(present, dtype=jnp.int32)
+    dest = jnp.where(present, n, ahead)
+    fresh_keys = jnp.zeros_like(keys).at[dest].set(keys, mode="drop")
+    fresh_vals = jnp.zeros_like(values).at[dest].set(values, mode="drop")
+
+    def body(carry):
+        i, s = carry
+        return i + 1, eh_insert(s, fresh_keys[i], fresh_vals[i])
+
+    _, st = jax.lax.while_loop(lambda c: c[0] < fresh, body,
+                               (jnp.int32(0), st))
+    return st, fresh
 
 
 def eh_lookup(st: EHState, key: jax.Array) -> jax.Array:

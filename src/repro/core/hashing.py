@@ -103,6 +103,25 @@ def probe_hit(probed: jnp.ndarray, key: jnp.ndarray):
     return jnp.any(live), jnp.argmax(live)
 
 
+def _row_ranks(row_keys: jnp.ndarray, keys: jnp.ndarray):
+    """The rank mask shared by :func:`probe_rows` and
+    :func:`probe_rows_slot`: returns ``(start, rank, hit)``, where
+    ``start`` (T, 1) is each key's first probe lane, ``rank`` (T, S) each
+    lane's rank in that key's cyclic probe sequence, and ``hit`` (T, 1)
+    the rank of the live match, ``S`` where there is none."""
+    slots = row_keys.shape[-1]
+    start = (hash_bucket(keys) % jnp.uint32(slots)).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row_keys.shape,
+                                    row_keys.ndim - 1)
+    rank = (lane - start + slots) % slots
+    empty = row_keys == jnp.uint32(EMPTY_SENTINEL)
+    first_empty = jnp.min(jnp.where(empty, rank, slots), axis=-1,
+                          keepdims=True)
+    live = (row_keys == keys.astype(jnp.uint32)) & (rank < first_empty)
+    hit = jnp.min(jnp.where(live, rank, slots), axis=-1, keepdims=True)
+    return start, rank, hit
+
+
 def probe_rows(row_keys: jnp.ndarray, row_vals: jnp.ndarray,
                keys: jnp.ndarray):
     """:func:`probe_hit` for a whole tile of keys at once, as a rank mask
@@ -116,20 +135,22 @@ def probe_rows(row_keys: jnp.ndarray, row_vals: jnp.ndarray,
     its rank is below the rank of the row's first EMPTY".  Returns
     ``(found, value)``, both (T, 1); ``value`` is 0 where not found."""
     slots = row_keys.shape[-1]
-    start = (hash_bucket(keys) % jnp.uint32(slots)).astype(jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, row_keys.shape,
-                                    row_keys.ndim - 1)
-    rank = (lane - start + slots) % slots
-    empty = row_keys == jnp.uint32(EMPTY_SENTINEL)
-    first_empty = jnp.min(jnp.where(empty, rank, slots), axis=-1,
-                          keepdims=True)
-    live = (row_keys == keys.astype(jnp.uint32)) & (rank < first_empty)
-    hit = jnp.min(jnp.where(live, rank, slots), axis=-1, keepdims=True)
+    _, rank, hit = _row_ranks(row_keys, keys)
     # ranks are a permutation of the lanes, so exactly one lane has the
     # hit's rank; int32 because the vector unit reduces signed lanes
     value = jnp.sum(jnp.where(rank == hit, row_vals, 0).astype(jnp.int32),
                     axis=-1, keepdims=True)
     return hit < slots, value.astype(jnp.uint32)
+
+
+def probe_rows_slot(row_keys: jnp.ndarray, keys: jnp.ndarray):
+    """:func:`probe_rows`, answering with the lane of the hit instead of
+    its value: the slot :func:`probe_slot` would overwrite.  Returns
+    ``(found, lane)``, both (T, 1); ``lane`` is meaningless where not
+    found."""
+    slots = row_keys.shape[-1]
+    start, _, hit = _row_ranks(row_keys, keys)
+    return hit < slots, (start + hit) % slots
 
 
 def probe_slot(probed: jnp.ndarray, key: jnp.ndarray):

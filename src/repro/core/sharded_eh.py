@@ -283,6 +283,14 @@ class ShardedShortcutEH:
     def routed_traditional(self) -> int:
         return self.group.routed_fallback
 
+    @property
+    def keys_in_place(self) -> int:
+        return sum(s.keys_in_place for s in self.shards)
+
+    @property
+    def keys_scanned(self) -> int:
+        return sum(s.keys_scanned for s in self.shards)
+
     def num_entries(self) -> int:
         return sum(int(eh.eh_num_entries(s.state)) for s in self.shards)
 

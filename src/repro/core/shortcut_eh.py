@@ -99,6 +99,11 @@ class ShortcutEH:
         self._shard = 0
         self._vfam = "eh_view"
         self._tfam = "eh_trad"
+        # insert's keys by how eh_insert_many took them: overwritten in
+        # place (present when their batch began) or run through the
+        # sequential loop (absent)
+        self.keys_in_place = 0
+        self.keys_scanned = 0
         self.mapper = ShortcutMapper(
             replay_create=self._replay_create,
             replay_update=self._replay_update,
@@ -227,16 +232,21 @@ class ShortcutEH:
         """Synchronous insert into the traditional index + enqueue
         maintenance (the paper's main-thread behaviour).
 
-        Profiler spans: ``insert.scan`` (the scan, waited for by the
-        depth reads), ``insert.lock`` (the wait for the mapper's lock),
-        ``insert.publish``, ``insert.touched``, ``insert.submit``."""
+        Profiler spans: ``insert.scan`` (``eh_insert_many``, waited for
+        by the depth reads), ``insert.lock`` (the wait for the mapper's
+        lock), ``insert.publish``, ``insert.touched``,
+        ``insert.submit``."""
         keys = jnp.asarray(keys, jnp.uint32)
         values = jnp.asarray(values, jnp.uint32)
         with TraceAnnotation("insert.scan"):
             old_g = int(self.state.global_depth)
             with _held(self.mapper.lock, "insert.lock"):
-                self.state = eh.eh_insert_many(self.state, keys, values)
-                new_g = int(self.state.global_depth)
+                self.state, fresh = eh.eh_insert_many(self.state, keys,
+                                                      values)
+                new_g, fresh = map(int, jax.device_get(
+                    (self.state.global_depth, fresh)))
+                self.keys_scanned += fresh
+                self.keys_in_place += keys.shape[0] - fresh
                 versions = self.mapper.record([GLOBAL_VIEW])
                 if self._cache is not None:
                     # keep the stacked traditional family warm at

@@ -87,6 +87,32 @@ class TestOracleParity:
         sharded.close()
 
 
+class TestInsertCounters:
+    """``keys_in_place`` / ``keys_scanned``: how ``eh_insert_many`` took
+    each inserted key (present keys overwritten in place, absent ones run
+    through the sequential loop), on one shard and summed over shards."""
+
+    @pytest.mark.parametrize("batch", ["present", "fresh", "mixed"])
+    @pytest.mark.parametrize("num_shards", [1, 4])
+    def test_counts_follow_presence(self, rng, batch, num_shards):
+        idx = ShardedShortcutEH(10, 8, 1024, num_shards=num_shards)
+        keys = unique_keys(rng, 400)
+        idx.insert(keys[:200], np.arange(200, dtype=np.uint32))
+        assert (idx.keys_in_place, idx.keys_scanned) == (0, 200)
+        kb = {"present": rng.choice(keys[:200], 300),   # with repeats
+              "fresh": keys[200:],
+              "mixed": np.concatenate([keys[150:250], keys[150:250]])}[batch]
+        idx.insert(kb, np.ones(kb.size, np.uint32))
+        fresh = int(np.isin(kb, keys[200:]).sum())
+        assert idx.keys_scanned == 200 + fresh
+        assert idx.keys_in_place == kb.size - fresh
+        assert idx.keys_in_place == sum(s.keys_in_place for s in idx.shards)
+        assert idx.keys_scanned == sum(s.keys_scanned for s in idx.shards)
+        if num_shards > 1:
+            assert sum(s.keys_scanned > 0 for s in idx.shards) > 1
+        idx.close()
+
+
 class TestShardLocality:
     @pytest.mark.parametrize("num_shards", [2, 8])
     def test_per_shard_invariants(self, rng, num_shards):

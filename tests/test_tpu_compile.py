@@ -81,3 +81,18 @@ def test_lookup_compiles_for_v5e(one_chip, entry, n):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, used
+
+
+def test_insert_many_compiles_for_v5e(one_chip):
+    """The index's insert at a bulk load's length (2^19 keys into a
+    depth-11 directory of 2,048 buckets of 512 slots) compiles for the
+    chip, classifying a tile at a time: its temporaries stay under
+    64 MiB."""
+    from repro.core import extendible_hashing as eh
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: eh.eh_create(11, 512, 2048)))
+    batch = jax.ShapeDtypeStruct((1 << 19,), jnp.uint32, sharding=one_chip)
+    mem = eh.eh_insert_many.lower(state, batch, batch).compile() \
+        .memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem.temp_size_in_bytes
