@@ -1,12 +1,21 @@
 """Traffic generation: the load keys and YCSB's request streams, from a
 seed.
 
-Record keys are the configuration's distinct 32-bit keys: a fixed
-bijection of the record number (MurmurHash3's 32-bit finalizer), with
-the two sentinels of the index (0 is kept out too, as 0xFFFFFFFF is the
-empty-slot and miss marker) skipped.  They do not depend on the seed,
-as YCSB's load keys do not.  The seed draws the loaded values and the
-request stream.
+Keys and values are as wide as the configuration says (``key_bits``,
+``value_bits``: 32 or 64), in the unsigned type of that width; the
+all-ones word of the value width is the miss marker.  Record keys are
+distinct and never a reserved word (0, and the all-ones word, the
+index's empty-slot marker):
+
+* at 32 bits, a fixed bijection of the record number (MurmurHash3's
+  32-bit finalizer), the reserved words skipped;
+* at 64 bits, YCSB's hashed keys (``insertorder=hashed``:
+  ``CoreWorkload.buildKeyName`` names record ``k`` ``"user" +
+  fnvhash64(k)``), a reserved word or a repeat skipped.
+
+They do not depend on the seed, as YCSB's load keys do not.  The seed
+draws the loaded values and the request stream; the streams draw record
+numbers, so they are the same at either width.
 
 The request streams are YCSB's (core workloads, ``CoreWorkload``), over
 record numbers:
@@ -28,10 +37,23 @@ from typing import Optional
 
 import numpy as np
 
-MISS = 0xFFFFFFFF
-# largest value a record may hold: 0xFFFFFFFF reads as a miss
-VALUE_MOD = 0xFFFFFFFF
 _KEY_START = 0x632BE5AB        # fixed: the load set never depends on a seed
+WORDS = {32: np.uint32, 64: np.uint64}
+
+
+def word(bits: int) -> type:
+    """The unsigned type of a key or value of ``bits`` bits."""
+    try:
+        return WORDS[int(bits)]
+    except KeyError:
+        raise ValueError(f"keys and values are 32 or 64 bits wide; "
+                         f"got {bits}") from None
+
+
+def miss(bits: int):
+    """The all-ones word of ``bits`` bits: the miss marker, and the
+    empty-slot marker of a key."""
+    return word(bits)(2 ** int(bits) - 1)
 
 
 def fmix32(x: np.ndarray) -> np.ndarray:
@@ -43,13 +65,6 @@ def fmix32(x: np.ndarray) -> np.ndarray:
     x *= np.uint32(0xC2B2AE35)
     x ^= x >> np.uint32(16)
     return x
-
-
-def record_keys(n: int) -> np.ndarray:
-    """The ``n`` record keys, in record order: distinct, never 0 or
-    0xFFFFFFFF, the same in every run."""
-    keys = fmix32(np.arange(n + 2, dtype=np.uint32) + np.uint32(_KEY_START))
-    return keys[(keys != 0) & (keys != np.uint32(MISS))][:n]
 
 
 FNV_OFFSET_BASIS_64 = 0xCBF29CE484222325
@@ -69,6 +84,25 @@ def fnvhash64(x: np.ndarray) -> np.ndarray:
         h *= np.uint64(FNV_PRIME_64)
         x >>= np.uint64(8)
     return np.where(h >> np.uint64(63), -h, h)     # two's-complement abs
+
+
+def record_keys(n: int, key_bits: int = 32) -> np.ndarray:
+    """The ``n`` record keys of ``key_bits`` bits, in record order:
+    distinct, never 0 or all ones, the same in every run."""
+    reserved = miss(key_bits)
+    if key_bits == 32:
+        keys = fmix32(np.arange(n + 2, dtype=np.uint32)
+                      + np.uint32(_KEY_START))
+        return keys[(keys != 0) & (keys != reserved)][:n]
+    m = n
+    while True:
+        keys = fnvhash64(np.arange(m, dtype=np.uint64))
+        first = np.zeros(m, bool)
+        first[np.unique(keys, return_index=True)[1]] = True
+        keys = keys[first & (keys != 0) & (keys != reserved)]
+        if keys.size >= n:
+            return keys[:n]
+        m += n - keys.size
 
 
 class ScrambledZipf:
@@ -127,10 +161,33 @@ def distribution(traffic: dict, n: int):
     raise ValueError(f"unknown request distribution {kind!r}")
 
 
-def load_values(seed: int, n: int) -> np.ndarray:
-    """The values the load stores, one per record, drawn from the seed."""
+def load_values(seed: int, n: int, value_bits: int = 32) -> np.ndarray:
+    """The values the load stores, one per record, drawn from the seed:
+    below the miss marker of ``value_bits`` bits."""
     rng = np.random.default_rng([seed, 1])
-    return rng.integers(0, VALUE_MOD, size=n, dtype=np.uint32)
+    return rng.integers(0, miss(value_bits), size=n, dtype=word(value_bits))
+
+
+def miss_probe(seed: int, keys: np.ndarray, size: int) -> np.ndarray:
+    """``size`` 64-bit keys that are not among ``keys``, drawn from the
+    seed: the first half each share their low 32-bit word with a stored
+    key, the rest their high word, and none is a reserved word.  An index
+    that drops either word of a key answers some of them with a stored
+    key's value instead of the miss marker."""
+    rng = np.random.default_rng([seed, 3])
+    low = np.uint64(0xFFFFFFFF)
+    half = size // 2
+    stored = keys[rng.integers(0, keys.size, size)]
+    kept = np.concatenate([stored[:half] & low, stored[half:] & ~low])
+    probe = np.empty(size, np.uint64)
+    todo = np.arange(size)
+    while todo.size:
+        fresh = rng.integers(0, 1 << 32, todo.size, dtype=np.uint64)
+        probe[todo] = kept[todo] | np.where(todo < half,
+                                            fresh << np.uint64(32), fresh)
+        p = probe[todo]
+        todo = todo[np.isin(p, keys) | (p == 0) | (p == miss(64))]
+    return probe
 
 
 @dataclass
@@ -141,10 +198,11 @@ class RequestPool:
     (records ``updates[i]``), then its reads (records ``reads[i]``).  The
     value an update stores is :meth:`update_values` of ``seq``, fresh
     for every issue, so a cycled request never rewrites what it wrote
-    before."""
+    before: consecutive values, wrapping below the miss marker."""
     reads: np.ndarray               # (P, R) record numbers
     updates: Optional[np.ndarray]   # (P, U) record numbers, or None
     value_base: int
+    value_bits: int = 32
 
     @property
     def requests(self) -> int:
@@ -155,12 +213,16 @@ class RequestPool:
 
     def update_values(self, seq: int) -> np.ndarray:
         u = self.updates.shape[1]
-        first = self.value_base + seq * u
-        return ((np.arange(u, dtype=np.int64) + first)
-                % VALUE_MOD).astype(np.uint32)
+        mod = int(miss(self.value_bits))
+        start = (self.value_base + seq * u) % mod
+        i = np.arange(u, dtype=np.uint64)
+        room = np.uint64(mod - start)           # values before the wrap
+        vals = np.where(i < room, np.uint64(start) + i, i - room)
+        return vals.astype(word(self.value_bits))
 
 
-def request_pool(seed: int, traffic: dict, n: int) -> RequestPool:
+def request_pool(seed: int, traffic: dict, n: int,
+                 value_bits: int = 32) -> RequestPool:
     """Draw the pool of requests of a traffic mix from the seed."""
     rng = np.random.default_rng([seed, 2])
     dist = distribution(traffic, n)
@@ -168,7 +230,7 @@ def request_pool(seed: int, traffic: dict, n: int) -> RequestPool:
     reads = dist.draw(rng, (p, int(traffic["reads_per_request"])))
     u = int(traffic.get("updates_per_request", 0))
     updates = dist.draw(rng, (p, u)) if u else None
-    base = int(rng.integers(0, VALUE_MOD))
+    base = int(rng.integers(0, miss(value_bits), dtype=np.uint64))
     return RequestPool(reads.astype(np.int32),
                        None if updates is None else updates.astype(np.int32),
-                       base)
+                       base, value_bits)

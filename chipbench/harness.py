@@ -12,6 +12,13 @@ The client is a closed loop with one outstanding request.  Request
 seed during set-up; it sends its updates through ``insert`` (the return
 is the acknowledgement), then its reads through ``lookup_batched``, and
 is complete when the read results are on the host.
+
+Keys and values are as wide as the configuration's ``key_bits`` and
+``value_bits`` say, from generation to the comparison.  Every read of
+the traffic hits a stored key, so at 64-bit keys the harness also reads,
+after the window, a probe of never-stored keys that each share one
+32-bit word with a stored key: each has to read the miss marker
+(``wrong_misses``), or the index has dropped part of a key.
 """
 from __future__ import annotations
 
@@ -168,9 +175,11 @@ def _run(jax, device, spec, cfg, traffic, workload, seed, seconds, trace,
     system_mod = load_module(root / "chipbench" / "systems"
                              / f"{cfg['system']}.py")
     n = int(cfg["records"])
-    keys = gen.record_keys(n)
-    values = gen.load_values(seed, n)
-    pool = gen.request_pool(seed, traffic, n)
+    key_bits = int(cfg.get("key_bits", 32))
+    value_bits = int(cfg.get("value_bits", 32))
+    keys = gen.record_keys(n, key_bits)
+    values = gen.load_values(seed, n, value_bits)
+    pool = gen.request_pool(seed, traffic, n, value_bits)
     read_keys = keys[pool.reads]
     upd_keys = None if pool.updates is None else keys[pool.updates]
     system = (faults.build(fault, cfg, system_mod.System, reference)
@@ -250,13 +259,18 @@ def _run(jax, device, spec, cfg, traffic, workload, seed, seconds, trace,
         stats = device.memory_stats() or {}
         memory_peak = int(stats.get("peak_bytes_in_use", 0))
 
-        # after the window: the mapper's replayed view, both routes
+        # after the window: the mapper's replayed view, both routes,
+        # and at 64-bit keys the miss probe on each
+        probe = (gen.miss_probe(seed, keys, read_keys.shape[1])
+                 if key_bits > 32 else None)
         in_sync_after = system.wait_in_sync(SYNC_TIMEOUT_S)
         for route in ("traditional", "shortcut"):
             system.force_route(route)
             system.wait_in_sync(SYNC_TIMEOUT_S)
             forced[("after", route)] = np.asarray(
                 system.lookup(read_keys[0]))
+            if probe is not None:
+                forced[("probe", route)] = np.asarray(system.lookup(probe))
         system.force_route(None)
         final_entries = system.entries()
         layout = system.layout() if trace else None
@@ -300,12 +314,18 @@ def _run(jax, device, spec, cfg, traffic, workload, seed, seconds, trace,
             "value": int(not (in_sync_after_load and in_sync_after)),
             "limit": 0},
     }
+    if probe is not None:
+        checks["wrong_misses"] = {"value": sum(
+            int(np.count_nonzero(forced[("probe", route)]
+                                 != gen.miss(value_bits)))
+            for route in ("traditional", "shortcut")), "limit": 0}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     requests = seq - first
     log(_window_summary(np.asarray(lat), compiles))
     log(f"compared {compared} requests' reads ({compared} x "
         f"{read_keys.shape[1]}) and the forced-route reads with the "
-        f"reference")
+        f"reference" + ("" if probe is None else
+                        f", and {probe.size} never-stored keys a route"))
 
     ctx = SimpleNamespace(
         workload=workload, config=cfg, traffic=traffic, seed=seed,
@@ -370,21 +390,20 @@ def _xplane(trace_dir: Path) -> str:
 def _lookup_bytes(layout, pool, read_keys, window_seqs, cfg):
     """Least bytes of the window's lookup requests, each pool entry
     weighted by how often the window issued it: ``[(weight,
-    roofline.LookupBytes)]``."""
-    skeys, sshard, sbucket, depth = layout
+    roofline.LookupBytes)]``.  Each key's shard, bucket and directory
+    slot are the system's, from ``layout()``; every read of the traffic
+    hits a stored key."""
+    skeys, sshard, sbucket, sslot = layout
     entries, counts = np.unique(
         np.asarray([pool.entry(s) for s in window_seqs]), return_counts=True)
     out = []
     for e, c in zip(entries, counts):
         q = read_keys[e]
         i = np.minimum(np.searchsorted(skeys, q), skeys.size - 1)
-        hit = skeys[i] == q
-        shard, bucket = sshard[i], sbucket[i]
-        slot = np.empty(q.size, np.int64)
-        for s in np.unique(shard):
-            m = shard == s
-            slot[m] = roofline.dir_slots(q[m], int(depth[s]))
         out.append((int(c), roofline.lookup_bytes(
-            shard, bucket, slot, hit, int(cfg["bucket_slots"]))))
+            sshard[i], sbucket[i], sslot[i], skeys[i] == q,
+            int(cfg["bucket_slots"]),
+            key_bytes=int(cfg.get("key_bits", 32)) // 8,
+            value_bytes=int(cfg.get("value_bits", 32)) // 8)))
     return out
 

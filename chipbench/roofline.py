@@ -10,7 +10,9 @@ chip's HBM peak.  The least bytes are the work itself, whatever
 implements it: each key read in, each result written out, one S-slot
 key row per distinct bucket the request resolves to, one value per hit,
 and, on the traditional path, one directory entry per distinct
-directory slot.  Padding is not counted.
+directory slot.  Keys and key rows count at the configuration's key
+width, results and hit values at its value width, and a directory
+entry (a bucket number) at 4 bytes.  Padding is not counted.
 """
 from __future__ import annotations
 
@@ -18,8 +20,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-WORD = 4                         # bytes of a key, a value, a directory entry
-HASH_C1 = 2654435761             # the index's directory hash (multiplicative)
+DIR_ENTRY = 4                    # bytes of a directory entry
 
 
 @dataclass(frozen=True)
@@ -44,15 +45,6 @@ def peaks(device_kind: str) -> Peaks:
                        f"known: {sorted(PEAKS)}") from None
 
 
-def dir_slots(keys: np.ndarray, depth: int) -> np.ndarray:
-    """Directory slot of each key: the top ``depth`` bits of the hash."""
-    h = (np.asarray(keys, np.uint64) * np.uint64(HASH_C1)) \
-        & np.uint64(0xFFFFFFFF)
-    if depth == 0:
-        return np.zeros(h.shape, np.int64)
-    return (h >> np.uint64(32 - depth)).astype(np.int64)
-
-
 @dataclass(frozen=True)
 class LookupBytes:
     """Least bytes of one lookup request, by part."""
@@ -71,7 +63,8 @@ class LookupBytes:
 
 def lookup_bytes(shard_of_key: np.ndarray, bucket_of_key: np.ndarray,
                  slot_of_key: np.ndarray, hit: np.ndarray,
-                 bucket_slots: int) -> LookupBytes:
+                 bucket_slots: int, key_bytes: int = 4,
+                 value_bytes: int = 4) -> LookupBytes:
     """Least bytes of one request of ``n`` keys.
 
     ``shard_of_key``, ``bucket_of_key`` and ``slot_of_key`` give each
@@ -84,7 +77,8 @@ def lookup_bytes(shard_of_key: np.ndarray, bucket_of_key: np.ndarray,
             | np.asarray(a, np.int64)
         return int(np.unique(pairs).size)
 
-    return LookupBytes(keys=WORD * n, results=WORD * n,
-                       rows=WORD * bucket_slots * distinct(bucket_of_key),
-                       hit_values=WORD * int(np.count_nonzero(hit)),
-                       directory=WORD * distinct(slot_of_key))
+    return LookupBytes(
+        keys=key_bytes * n, results=value_bytes * n,
+        rows=key_bytes * bucket_slots * distinct(bucket_of_key),
+        hit_values=value_bytes * int(np.count_nonzero(hit)),
+        directory=DIR_ENTRY * distinct(slot_of_key))

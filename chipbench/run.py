@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--fault", default=None,
                     choices=("control", "state_unchanged", "half_batch",
-                             "altered_answer"))
+                             "altered_answer", "low_word_only"))
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]

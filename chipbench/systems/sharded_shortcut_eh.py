@@ -1,7 +1,10 @@
 """The sharded Shortcut-EH index (``repro.core.sharded_eh``) as the
 harness drives it: through its public entry points, ``insert`` for
 updates (returning is the acknowledgement) and ``lookup_batched`` for
-reads, with the mapper on its own threads."""
+reads, with the mapper on its own threads.
+
+The index holds 32-bit keys and values (ROADMAP R3): a configuration of
+any other width is refused at construction, not wrapped into 32 bits."""
 from __future__ import annotations
 
 import math
@@ -15,6 +18,13 @@ ROUTES = ("traditional", "shortcut")
 
 class System:
     def __init__(self, cfg: dict):
+        widths = (int(cfg.get("key_bits", 32)),
+                  int(cfg.get("value_bits", 32)))
+        if widths != (32, 32):
+            raise ValueError(
+                f"the index holds 32-bit keys and values (ROADMAP R3); the "
+                f"configuration asks for {widths[0]}-bit keys and "
+                f"{widths[1]}-bit values")
         from repro.core.sharded_eh import ShardedShortcutEH
         self.cfg = cfg
         self.idx = ShardedShortcutEH(
@@ -83,24 +93,28 @@ class System:
 
     def layout(self):
         """Where each stored key lives, from the index's state: returns
-        ``(keys, shard, bucket, depth)`` with ``keys`` sorted and
-        ``depth`` the per-shard global depths.  Read outside the timed
-        region."""
-        keys, shard, bucket, depth = [], [], [], []
+        ``(keys, shard, bucket, slot)`` with ``keys`` sorted and ``slot``
+        each key's directory slot in its shard, by the index's own
+        directory hash at the shard's global depth.  Read outside the
+        timed region."""
+        from repro.core import hashing
+        keys, shard, bucket, slot = [], [], [], []
         for s, sh in enumerate(self.idx.shards):
             st = sh.state
             nb = int(st.num_buckets)
             bk = np.asarray(st.bucket_keys[:nb])
             live = bk != EMPTY
             b = np.nonzero(live)[0]
-            keys.append(bk[live])
+            k = bk[live]
+            keys.append(k)
             bucket.append(b)
             shard.append(np.full(b.size, s))
-            depth.append(int(st.global_depth))
+            slot.append(np.asarray(hashing.dir_slot(
+                hashing.hash_dir(k), st.global_depth)).astype(np.int64))
         keys = np.concatenate(keys)
         order = np.argsort(keys)
         return (keys[order], np.concatenate(shard)[order],
-                np.concatenate(bucket)[order], np.asarray(depth))
+                np.concatenate(bucket)[order], np.concatenate(slot)[order])
 
     def close(self) -> None:
         self.idx.close()

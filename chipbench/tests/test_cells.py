@@ -21,6 +21,7 @@ def test_cell_runs_correct_with_its_end_to_end_metrics(workload):
     r = tiny.run(workload)
     assert r["correct"] is True, r["checks"]
     assert list(r)[-1] == "checks"
+    assert "wrong_misses" not in r["checks"]     # no probe at 32 bits
     want = {m["name"] for m in BENCH["end_to_end"]
             if workload in m.get("workloads", [workload])}
     assert set(r["metrics"]) == want

@@ -18,3 +18,9 @@ def test_fault_makes_the_run_incorrect(workload, fault):
     r = tiny.run(workload, fault=fault, seconds=0.3)
     assert r["correct"] is False
     assert r["checks"]["wrong_reads"]["value"] > 0
+
+
+def test_low_word_only_is_refused_at_32_bit_keys():
+    # 32-bit keys have no high word to drop: nothing to catch
+    with pytest.raises(ValueError, match="32-bit"):
+        tiny.run("ycsb-c.uniform.flat", fault="low_word_only", seconds=0.3)
