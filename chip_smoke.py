@@ -229,7 +229,8 @@ def run(args) -> None:
         print(f"lowered: {', '.join(lowered)} contain tpu_custom_call")
 
         resident = idx.operands.resident_bytes()
-        state = sum(int(a.nbytes) for s in idx.shards for a in s.state)
+        state = sum(int(a.nbytes) for s in idx.shards
+                    for a in jax.tree.leaves(s.state))
         stats = dev.memory_stats() or {}
         print("device bytes: operand stacks "
               + ", ".join(f"{k} {mib(v)}" for k, v in sorted(resident.items()))

@@ -48,13 +48,15 @@ equivalent depth, not depth - shard_bits).
 """
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import extendible_hashing as eh
-from repro.core.hashing import HASH_C1
+from repro.core import hashing
 from repro.core.shortcut_eh import ShortcutEH
 from repro.runtime.mapper import GLOBAL_VIEW, MaintenanceStats
 from repro.runtime.operand_cache import StackedOperandCache
@@ -68,13 +70,13 @@ __all__ = ["ShardedShortcutEH", "partition_by_shard", "shard_of_keys",
            "shard_order"]
 
 
-def shard_of_keys(keys: np.ndarray, shard_bits: int) -> np.ndarray:
+def shard_of_keys(keys: np.ndarray, shard_bits: int,
+                  key_bits: int = 32) -> np.ndarray:
     """Shard index per key: the top ``shard_bits`` of the directory hash
     (host twin of ``hashing.hash_dir`` + MSB slot rule)."""
     if shard_bits == 0:
         return np.zeros(np.asarray(keys).shape, np.int64)
-    h = (np.asarray(keys, np.uint64) * np.uint64(HASH_C1)) \
-        & np.uint64(0xFFFFFFFF)
+    h = hashing.hash_dir_np(keys, key_bits)
     return (h >> np.uint64(32 - shard_bits)).astype(np.int64)
 
 
@@ -101,30 +103,43 @@ class ShardedShortcutEH:
     the constructor arguments (capacity is per shard — sizing it as the
     flat index's keeps the sharded index at least as drop-free as the
     flat one under any skew, the precondition for bit-for-bit parity).
+
+    ``key_bits``/``value_bits`` (32 or 64, default 32) fix the width of
+    keys and values for every shard (:class:`ShortcutEH`).  At 64 bits
+    the device holds each as two uint32 words: ``lookup_batched`` splits
+    its keys once per call and joins the answers into a numpy uint64
+    array, timed by ``split_seconds`` with the shards' insert splits.
+    A key or value array whose dtype is wider than the width raises
+    ``ValueError``: it is never wrapped.
     """
 
     def __init__(self, max_global_depth: int, bucket_slots: int,
                  capacity: int, *, num_shards: int = 1,
                  fan_in_threshold: float = 8.0,
                  poll_interval: float = 0.025, async_mapper: bool = False,
-                 routing_factory=None):
+                 routing_factory=None, key_bits: int = 32,
+                 value_bits: int = 32):
         if num_shards < 1 or num_shards & (num_shards - 1):
             raise ValueError(f"num_shards must be a power of two, "
                              f"got {num_shards}")
         self.num_shards = num_shards
         self.shard_bits = num_shards.bit_length() - 1
+        self.key_bits, self.value_bits = key_bits, value_bits
+        self._key_type = hashing.word_type(key_bits)
+        self._value_type = hashing.word_type(value_bits)
+        self._split_seconds = 0.0           # lookup_batched's own
         self.shards = [
             ShortcutEH(max_global_depth, bucket_slots, capacity,
                        fan_in_threshold=fan_in_threshold,
                        poll_interval=poll_interval,
                        async_mapper=async_mapper,
                        routing=(routing_factory(i) if routing_factory
-                                else None))
+                                else None),
+                       key_bits=key_bits, value_bits=value_bits)
             for i in range(num_shards)]
         self.group = MapperGroup(
             [s.mapper for s in self.shards],
-            router=lambda key: int(shard_of_keys(
-                np.asarray([key], np.uint32), self.shard_bits)[0]))
+            router=lambda key: int(self.shard_of([key])[0]))
         # primary storage of the stacked lookup operands (families
         # "eh_view" / "eh_trad", DESIGN.md §4.4): replays publish their
         # shard's slice straight into the stack at publish time, so the
@@ -139,7 +154,17 @@ class ShardedShortcutEH:
 
     def shard_of(self, keys) -> np.ndarray:
         """Vectorized key -> shard index (top hash bits)."""
-        return shard_of_keys(np.asarray(keys, np.uint32), self.shard_bits)
+        return shard_of_keys(self._keys(keys), self.shard_bits,
+                             self.key_bits)
+
+    def _keys(self, keys) -> np.ndarray:
+        return np.asarray(hashing.check_width(keys, self.key_bits, "key"),
+                          self._key_type)
+
+    def _answer(self, out: np.ndarray):
+        """Answers in input order as the caller gets them: a device
+        array at 32-bit values, a numpy uint64 array at 64."""
+        return jnp.asarray(out) if self.value_bits == 32 else out
 
     # -- main-thread API ----------------------------------------------------
 
@@ -149,8 +174,10 @@ class ShardedShortcutEH:
         Strictly shard-local: each sub-insert takes only its shard's
         lock, bumps only its shard's version, and enqueues maintenance
         only on its shard's queue."""
-        keys = np.asarray(keys, np.uint32)
-        values = np.asarray(values, np.uint32)
+        keys = self._keys(keys)
+        values = np.asarray(
+            hashing.check_width(values, self.value_bits, "value"),
+            self._value_type)
         if self.num_shards == 1:
             self.shards[0].insert(keys, values)
             return
@@ -168,9 +195,9 @@ class ShardedShortcutEH:
 
         Cross-shard batching: one argsort pass, static padded per-shard
         sub-batches (pad lanes are dropped on scatter-back)."""
-        keys = np.asarray(keys, np.uint32)
+        keys = self._keys(keys)
         if keys.size == 0:
-            return jnp.zeros((0,), jnp.uint32)
+            return self._answer(np.empty(0, self._value_type))
         if self.num_shards == 1:
             return self.shards[0].lookup(keys)
         sid = self.shard_of(keys)
@@ -179,13 +206,13 @@ class ShardedShortcutEH:
         padded, counts, order, rank = partition_by_shard(
             keys, sid, self.num_shards, cap,
             order=order, counts=counts, starts=starts)
-        results = np.empty((self.num_shards, cap), np.uint32)
+        results = np.empty((self.num_shards, cap), self._value_type)
         for s in range(self.num_shards):
             if counts[s]:
                 results[s] = np.asarray(self.shards[s].lookup(padded[s]))
-        out = np.empty(keys.size, np.uint32)
+        out = np.empty(keys.size, self._value_type)
         out[order] = results[sid[order], rank]
-        return jnp.asarray(out)
+        return self._answer(out)
 
     def lookup_batched(self, keys, *, tile: int = 256) -> jax.Array:
         """Fused cross-shard lookup: ONE Pallas dispatch for all shards,
@@ -203,22 +230,30 @@ class ShardedShortcutEH:
 
         Profiler spans, one per step: ``lookup.bucketize``,
         ``lookup.gate``, ``lookup.operands``, ``lookup.dispatch``,
-        ``lookup.wait`` (results to the host) and ``lookup.scatter``."""
+        ``lookup.wait`` (results to the host) and ``lookup.scatter``; at
+        64-bit keys ``lookup.split`` after the bucketize (the keys into
+        words), at 64-bit values after the wait (the answers' words
+        joined)."""
         from repro.kernels.eh_lookup import (sharded_eh_lookup,
                                              sharded_routed_lookup,
                                              sharded_shortcut_lookup)
-        keys = np.asarray(keys, np.uint32)
+        keys = self._keys(keys)
         if keys.size == 0:
             # no padding, no operand refresh, no dispatch, no route
             # counters — an empty batch must not touch the device
-            return jnp.zeros((0,), jnp.uint32)
+            return self._answer(np.empty(0, self._value_type))
         with TraceAnnotation("lookup.bucketize"):
-            sid = self.shard_of(keys)
+            sid = shard_of_keys(keys, self.shard_bits, self.key_bits)
             order, counts, starts = shard_order(sid, self.num_shards)
             cap = pad_batch(int(counts.max()))
             padded, counts, order, rank = partition_by_shard(
                 keys, sid, self.num_shards, cap,
                 order=order, counts=counts, starts=starts)
+        if self.key_bits == 64:
+            with TraceAnnotation("lookup.split"):
+                t = time.perf_counter()
+                padded = hashing.split_words(padded)      # (N, 2, cap)
+                self._split_seconds += time.perf_counter() - t
         # Gate every shard FIRST (each policy decides exactly once — no
         # short-circuit), then read publish epochs/flags: replays
         # publish into the stack BEFORE bumping view_epoch and BEFORE
@@ -261,10 +296,15 @@ class ShardedShortcutEH:
                                             *view_ops, flags, tile=tile)
         with TraceAnnotation("lookup.wait"):
             res = np.asarray(res)
+        if self.value_bits == 64:
+            with TraceAnnotation("lookup.split"):
+                t = time.perf_counter()
+                res = hashing.join_words(res)             # (N, cap)
+                self._split_seconds += time.perf_counter() - t
         with TraceAnnotation("lookup.scatter"):
-            out = np.empty(keys.size, np.uint32)
+            out = np.empty(keys.size, self._value_type)
             out[order] = res[sid[order], rank]
-            return jnp.asarray(out)
+            return self._answer(out)
 
     # -- aggregated bookkeeping ----------------------------------------------
 
@@ -290,6 +330,13 @@ class ShardedShortcutEH:
     @property
     def keys_scanned(self) -> int:
         return sum(s.keys_scanned for s in self.shards)
+
+    @property
+    def split_seconds(self) -> float:
+        """Host seconds spent splitting 64-bit keys and values into
+        words and joining answers from them; 0 at 32 bits."""
+        return self._split_seconds + sum(s.split_seconds
+                                         for s in self.shards)
 
     def num_entries(self) -> int:
         return sum(int(eh.eh_num_entries(s.state)) for s in self.shards)
@@ -321,12 +368,10 @@ class ShardedShortcutEH:
             if not rep["ok"]:
                 out["ok"] = False
                 out["errors"] += [f"shard {s}: {e}" for e in rep["errors"]]
-            st = shard.state
-            nb = int(st.num_buckets)
-            bk = np.asarray(st.bucket_keys[:nb])
-            live = bk[bk != np.uint32(0xFFFFFFFF)]
+            bk = eh.host_keys(shard.state)
+            live = bk[bk != hashing.all_ones(self.key_bits)]
             if live.size:
-                owners = shard_of_keys(live, self.shard_bits)
+                owners = shard_of_keys(live, self.shard_bits, self.key_bits)
                 if not (owners == s).all():
                     out["ok"] = False
                     out["errors"].append(

@@ -30,6 +30,7 @@ The asynchronous, version-gated architecture is unchanged.
 """
 from __future__ import annotations
 
+import time
 from contextlib import contextmanager
 from typing import Optional
 
@@ -39,7 +40,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import extendible_hashing as eh
-from repro.core import rewiring
+from repro.core import hashing, rewiring
 from repro.runtime.mapper import (GLOBAL_VIEW, FanInRouting,
                                   MaintenanceStats, ShortcutMapper)
 
@@ -80,13 +81,25 @@ class ShortcutEH:
     A custom ``routing`` policy (e.g.
     :class:`~repro.runtime.mapper.HysteresisRouting`) may replace the
     default fan-in threshold rule.
+
+    ``key_bits``/``value_bits`` (32 or 64) fix the width of keys and
+    values.  At 64 bits the host splits each key and value into its two
+    uint32 words on the way in and joins the words of each answer on the
+    way out (``split_seconds`` times both); ``lookup`` then answers with
+    a numpy uint64 array.  An array whose dtype is wider than the width
+    is refused, never wrapped.
     """
 
     def __init__(self, max_global_depth: int, bucket_slots: int,
                  capacity: int, *, fan_in_threshold: float = 8.0,
                  poll_interval: float = 0.025, async_mapper: bool = False,
-                 routing=None):
-        self.state = eh.eh_create(max_global_depth, bucket_slots, capacity)
+                 routing=None, key_bits: int = 32, value_bits: int = 32):
+        self.state = eh.eh_create(max_global_depth, bucket_slots, capacity,
+                                  key_bits, value_bits)
+        self.key_bits, self.value_bits = key_bits, value_bits
+        # host seconds spent splitting 64-bit keys and values into words
+        # and joining answers from them (0 at 32 bits)
+        self.split_seconds = 0.0
         # The composed view is ONE atomically-swapped tuple
         # (view_keys, view_vals, view_log2): replays publish a fully
         # built tuple and readers snapshot it once, so a reader racing
@@ -226,6 +239,32 @@ class ShortcutEH:
         v = self.view_snapshot()
         return -1 if v is None else v[2]
 
+    # -- the host's words ----------------------------------------------------
+
+    def _to_device(self, a, bits: int, what: str, span: str) -> jax.Array:
+        """A host batch as the device's uint32 words: ``(n,)`` at 32
+        bits, ``(2, n)`` at 64 (split in profiler span ``span``)."""
+        a = hashing.check_width(a, bits, what)
+        if bits == 32:
+            return jnp.asarray(a, jnp.uint32)
+        with TraceAnnotation(span):
+            t = time.perf_counter()
+            words = hashing.split_words(np.asarray(a))
+            self.split_seconds += time.perf_counter() - t
+        return jnp.asarray(words)
+
+    def _to_host(self, res: jax.Array, span: str):
+        """Answers as the caller gets them: as they are at 32-bit values,
+        joined into a numpy uint64 array at 64."""
+        if self.value_bits == 32:
+            return res
+        res = np.asarray(res)
+        with TraceAnnotation(span):
+            t = time.perf_counter()
+            out = hashing.join_words(res)
+            self.split_seconds += time.perf_counter() - t
+        return out
+
     # -- main-thread API ----------------------------------------------------
 
     def insert(self, keys, values) -> None:
@@ -235,9 +274,10 @@ class ShortcutEH:
         Profiler spans: ``insert.scan`` (``eh_insert_many``, waited for
         by the depth reads), ``insert.lock`` (the wait for the mapper's
         lock), ``insert.publish``, ``insert.touched``,
-        ``insert.submit``."""
-        keys = jnp.asarray(keys, jnp.uint32)
-        values = jnp.asarray(values, jnp.uint32)
+        ``insert.submit``; at 64 bits first ``insert.split``."""
+        keys = self._to_device(keys, self.key_bits, "key", "insert.split")
+        values = self._to_device(values, self.value_bits, "value",
+                                 "insert.split")
         with TraceAnnotation("insert.scan"):
             old_g = int(self.state.global_depth)
             with _held(self.mapper.lock, "insert.lock"):
@@ -246,7 +286,7 @@ class ShortcutEH:
                 new_g, fresh = map(int, jax.device_get(
                     (self.state.global_depth, fresh)))
                 self.keys_scanned += fresh
-                self.keys_in_place += keys.shape[0] - fresh
+                self.keys_in_place += keys.shape[-1] - fresh
                 versions = self.mapper.record([GLOBAL_VIEW])
                 if self._cache is not None:
                     # keep the stacked traditional family warm at
@@ -266,8 +306,9 @@ class ShortcutEH:
                 self.mapper.submit_create([GLOBAL_VIEW], versions)
         else:
             with TraceAnnotation("insert.touched"):
-                slots = eh.dir_slot(eh.hash_dir(keys),
-                                    self.state.global_depth)
+                slots = eh.dir_slot(
+                    eh.hash_dir(hashing.words(keys, self.state.key_words)),
+                    self.state.global_depth)
                 touched = np.unique(np.asarray(self.state.directory[slots]))
             with TraceAnnotation("insert.submit", version=versions[0]):
                 self.mapper.submit_update([GLOBAL_VIEW], versions,
@@ -275,7 +316,10 @@ class ShortcutEH:
 
     def lookup(self, keys) -> jax.Array:
         """Route through the shortcut when in sync and fan-in permits."""
-        keys = jnp.asarray(keys, jnp.uint32)
+        keys = self._to_device(keys, self.key_bits, "key", "lookup.split")
+        return self._to_host(self._lookup(keys), "lookup.split")
+
+    def _lookup(self, keys: jax.Array) -> jax.Array:
         # gate FIRST, snapshot after: a replay landing in between
         # publishes a strictly newer view, which the gate's verdict
         # still covers; snapshotting first would let the gate certify

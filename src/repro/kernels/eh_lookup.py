@@ -43,6 +43,15 @@ lives in SMEM, where the scalar unit reads it.  Per tile:
      gathered rows (``hashing.probe_rows``), and a transpose returns the
      per-key results to the lane-major output tile.
 
+Keys and values of 64 bits are two uint32 words each (``core/hashing``).
+Such keys come as ``(..., 2, K)`` word planes, high words first, where
+32-bit keys come as ``(..., K)``; a 64-bit page of S slots is one
+``2 * S``-lane key row (high words in lanes ``[0, S)``, low words in
+``[S, 2S)``) and one value row laid out the same way, so the row gather
+moves the same bytes as a 32-bit page of ``2 * S`` slots.  The answers
+have the value's words: ``(..., K)``, or ``(..., 2, K)`` at 64-bit
+values.  The widths follow from the shapes, and so are static.
+
 ``tile`` must be a multiple of 128 (the lane width).  ``interpret=None``
 compiles on a TPU and interprets elsewhere (``kernels/backend.py``).
 """
@@ -85,9 +94,11 @@ def _row(col):
 
 def _resolve_tile(keys_ref, g, dir_ref, bk_ref, bv_ref, out_ref,
                   rows_k, rows_v, slot_v, slot_s, sem):
-    """THE lookup body: resolve one (1, tile) key tile against one
+    """THE lookup body: resolve one (words, tile) key tile against one
     shard's pages.  ``dir_ref`` None = shortcut (the slot IS the row)."""
-    keys = keys_ref[...]
+    kw, tile = keys_ref.shape
+    keys = keys_ref[...] if kw == 1 else tuple(
+        keys_ref[pl.ds(w, 1), :] for w in range(kw))
     slot_v[...] = hashing.dir_slot(hashing.hash_dir(keys), g)
     copy = pltpu.make_async_copy(slot_v, slot_s, sem)
     copy.start()
@@ -103,10 +114,14 @@ def _resolve_tile(keys_ref, g, dir_ref, bk_ref, bv_ref, out_ref,
         rows_v[pl.ds(i, 1), :] = bv_ref[pl.ds(row, 1), :]
         return carry
 
-    jax.lax.fori_loop(0, keys.shape[1], gather, 0)
+    jax.lax.fori_loop(0, tile, gather, 0)
     found, value = hashing.probe_rows(rows_k[...], rows_v[...],
-                                      _column(keys))
-    out_ref[...] = _row(jnp.where(found, value, jnp.uint32(MISS)))
+                                      hashing.per_word(_column, keys))
+    if not isinstance(value, tuple):
+        out_ref[...] = _row(jnp.where(found, value, jnp.uint32(MISS)))
+        return
+    for w, v in enumerate(value):
+        out_ref[pl.ds(w, 1), :] = _row(jnp.where(found, v, jnp.uint32(MISS)))
 
 
 def _lookup_kernel(gd_ref, keys_ref, *refs, two_level: bool):
@@ -149,13 +164,18 @@ def _stacked_select_kernel(sc_ref, keys_ref, vk_ref, vv_ref, out_ref,
                   *scratch)
 
 
+def _lanes(pool) -> int:
+    """A pool row's lanes in VMEM: padded to whole 128-lane rows."""
+    return -(-pool.shape[-1] // _LANES) * _LANES
+
+
 def _vmem_limit(pools, tile: int) -> int:
     """Scoped VMEM for one grid cell: the single-buffered pool blocks, the
-    gather buffers and the double-buffered key/output tiles, every row
-    padded to whole 128-lane rows."""
-    lanes = -(-pools[0].shape[-1] // _LANES) * _LANES
-    resident = sum(p.shape[1] for p in pools) * lanes * 4
-    gather = 2 * tile * lanes * 4
+    gather buffers (a key row and a value row per key) and the
+    double-buffered key/output tiles (whose word rows pad to 8
+    sublanes), every row padded to whole 128-lane rows."""
+    resident = sum(p.shape[1] * _lanes(p) for p in pools) * 4
+    gather = tile * (_lanes(pools[0]) + _lanes(pools[1])) * 4
     tiles = 5 * _SUBLANES * tile * 4
     return max(_SCOPED_VMEM_DEFAULT,
                resident + gather + tiles + _VMEM_MARGIN)
@@ -166,15 +186,25 @@ def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
     """What every entry point calls: ONE ``pallas_call`` over grid (key
     rows, key tiles).
 
-    keys (B, n); directory (N, D) or None; pools: (N, R, S) arrays;
+    keys (B, n), or (B, 2, n) words; directory (N, D) or None; pools:
+    (N, R, words * S) arrays, key and value pools alternating;
     ``select(b, sc)`` maps grid row b (and the scalar-prefetch ref) to
-    the shard block its pages come from.  Returns (B, n) uint32."""
+    the shard block its pages come from.  Returns (B, n) uint32, or
+    (B, 2, n) at 64-bit values."""
     if tile % _LANES:
         raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
-    B, n = keys.shape
+    n = keys.shape[-1]
     pad = (-n) % tile
-    keys = jnp.pad(keys.astype(jnp.uint32), ((0, 0), (0, pad)))[:, None, :]
-    key_spec = pl.BlockSpec((None, 1, tile), lambda b, i, sc: (b, 0, i))
+    if keys.ndim == 2:
+        keys = jnp.pad(keys.astype(jnp.uint32),
+                       ((0, 0), (0, pad)))[:, None, :]
+    else:
+        keys = jnp.pad(keys.astype(jnp.uint32), ((0, 0), (0, 0), (0, pad)))
+    B, kw, _ = keys.shape
+    slots = pools[0].shape[-1] // kw
+    vw = pools[1].shape[-1] // slots
+    key_spec = pl.BlockSpec((None, kw, tile), lambda b, i, sc: (b, 0, i))
+    out_spec = pl.BlockSpec((None, vw, tile), lambda b, i, sc: (b, 0, i))
 
     def per_shard(block, **kw):
         return pl.BlockSpec((None,) + block,
@@ -196,23 +226,24 @@ def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
     for p in pools:
         in_specs.append(per_shard(p.shape[1:]))
         args.append(p)
-    slots = pools[0].shape[-1]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, (n + pad) // tile),
-            in_specs=in_specs, out_specs=key_spec,
-            scratch_shapes=[pltpu.VMEM((tile, slots), jnp.uint32),
-                            pltpu.VMEM((tile, slots), jnp.uint32),
+            in_specs=in_specs, out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((tile, pools[0].shape[-1]),
+                                       jnp.uint32),
+                            pltpu.VMEM((tile, pools[1].shape[-1]),
+                                       jnp.uint32),
                             pltpu.VMEM((1, tile), jnp.int32),
                             pltpu.SMEM((1, tile), jnp.int32),
                             pltpu.SemaphoreType.DMA(())]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, n + pad), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((B, vw, n + pad), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(pools, tile)),
         interpret=resolve_interpret(interpret),
     )(scalars, *args)
-    return out[:, 0, :n]
+    return out[:, 0, :n] if vw == 1 else out[..., :n]
 
 
 def _by_row(b, sc):
@@ -226,9 +257,10 @@ def _by_row(b, sc):
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def eh_lookup(keys, directory, bucket_keys, bucket_vals, global_depth, *,
               tile: int = 256, interpret: Optional[bool] = None):
-    """Traditional EH lookup: keys (K,) -> values (K,) (MISS on absent).
+    """Traditional EH lookup: keys (K,) -> values (K,) (MISS on absent);
+    64-bit keys or values as (2, K) words.
 
-    directory: (D,) int32; bucket_keys/vals: (C, S) uint32."""
+    directory: (D,) int32; bucket_keys/vals: (C, words * S) uint32."""
     return sharded_eh_lookup(
         keys[None], directory[None], bucket_keys[None], bucket_vals[None],
         jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
@@ -240,7 +272,7 @@ def shortcut_lookup(keys, view_keys, view_vals, global_depth, *,
                     tile: int = 256, interpret: Optional[bool] = None):
     """Shortcut lookup over the composed view: one indirection fewer.
 
-    view_keys/vals: (2^g_cap, S) — slot-indexed bucket pages."""
+    view_keys/vals: (2^g_cap, words * S) — slot-indexed bucket pages."""
     return sharded_shortcut_lookup(
         keys[None], view_keys[None], view_vals[None],
         jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
@@ -258,10 +290,11 @@ def sharded_eh_lookup(keys, directories, bucket_keys, bucket_vals,
                       interpret: Optional[bool] = None):
     """Traditional lookup across N stacked shards.
 
-    keys: (N, K) — shard-bucketized, padded to a static per-shard
-    capacity (pad lanes return MISS or a stray hit and are dropped by
-    the caller's scatter-back); directories: (N, D); bucket_keys/vals:
-    (N, C, S); global_depths: (N,).  Returns (N, K) uint32."""
+    keys: (N, K), or (N, 2, K) words — shard-bucketized, padded to a
+    static per-shard capacity (pad lanes return MISS or a stray hit and
+    are dropped by the caller's scatter-back); directories: (N, D);
+    bucket_keys/vals: (N, C, words * S); global_depths: (N,).  Returns
+    (N, K) uint32, or (N, 2, K) at 64-bit values."""
     return _call(functools.partial(_lookup_kernel, two_level=True),
                  global_depths.astype(jnp.int32), keys, directories,
                  (bucket_keys, bucket_vals), select=_by_row, tile=tile,
@@ -286,7 +319,8 @@ def stacked_shortcut_lookup(keys, view_keys, view_vals, view_log2s,
     """Single-shard shortcut lookup resolved straight off the stacked
     primary storage (``runtime/operand_cache``, DESIGN.md §4.4).
 
-    keys: (K,); view_keys/vals: the full (N, V, S) stacks; view_log2s:
+    keys: (K,) or (2, K); view_keys/vals: the full (N, V, words * S)
+    stacks; view_log2s:
     (N,); ``shard`` selects which block the grid reads — via scalar
     prefetch, so all shards (and all shard *indices*) share one compiled
     specialization, and the flat per-shard lookup path needs no device

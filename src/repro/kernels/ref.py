@@ -84,60 +84,74 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens, *,
     return decode_attention_ref(q, k_ctx, v_ctx, seq_lens, softcap=softcap)
 
 
-def eh_lookup_ref(keys, directory, bucket_keys, bucket_vals,
-                  global_depth) -> jax.Array:
-    """Batched EH lookup: hash -> directory slot -> bucket probe.
+def _probe_ref(keys, rows_of, key_lanes: int, val_lanes: int):
+    """Naive bucket probe of every key: hash, row, cyclic probe by
+    gathers, every word compared, first EMPTY ends the probe.
 
-    keys: (N,) uint32; directory: (D,) int32; bucket_keys/vals: (C, S).
-    Returns (N,) uint32 values (0xFFFFFFFF on miss)."""
-    from repro.core.extendible_hashing import (EMPTY_KEY, MISS, dir_slot,
-                                               hash_dir, hash_bucket)
-    S = bucket_keys.shape[1]
+    ``keys`` (N,) uint32, or (W, N) words of wider keys (high first);
+    ``rows_of(key)`` gives a key's (key row, value row), each word in
+    its own lane block (a wide key as the tuple of its words).  Returns
+    (N,), or (V, N) at V-word values."""
+    from repro.core.hashing import (EMPTY_SENTINEL, MISS_SENTINEL,
+                                    hash_bucket)
+    keys = keys.astype(jnp.uint32)
+    kw = 1 if keys.ndim == 1 else keys.shape[0]
+    S = key_lanes // kw
+    vw = val_lanes // S
 
     def one(key):
-        slot = dir_slot(hash_dir(key), global_depth)
-        b = directory[slot]
-        row_k = bucket_keys[b]
-        row_v = bucket_vals[b]
+        if kw > 1:
+            key = tuple(key[w] for w in range(kw))
+        row_k, row_v = rows_of(key)
         start = hash_bucket(key) % jnp.uint32(S)
         pos = ((start + jnp.arange(S, dtype=jnp.uint32))
                % jnp.uint32(S)).astype(jnp.int32)
-        probed = row_k[pos]
-        hit = probed == key
-        empties = probed == EMPTY_KEY
+        hit = jnp.ones((S,), bool)
+        empties = jnp.ones((S,), bool)
+        for w, kword in enumerate(key if kw > 1 else (key,)):
+            probed = row_k[w * S + pos]
+            hit &= probed == kword
+            empties &= probed == jnp.uint32(EMPTY_SENTINEL)
         before = jnp.cumsum(empties.astype(jnp.int32)) \
             - empties.astype(jnp.int32)
         live = hit & (before == 0)
         found = jnp.any(live)
-        return jnp.where(found, row_v[pos[jnp.argmax(live)]], MISS)
+        j = pos[jnp.argmax(live)]
+        return jnp.stack([jnp.where(found, row_v[w * S + j],
+                                    jnp.uint32(MISS_SENTINEL))
+                          for w in range(vw)])
 
-    return jax.vmap(one)(keys.astype(jnp.uint32))
+    out = jax.vmap(one, in_axes=keys.ndim - 1, out_axes=1)(keys)
+    return out[0] if vw == 1 else out
+
+
+def eh_lookup_ref(keys, directory, bucket_keys, bucket_vals,
+                  global_depth) -> jax.Array:
+    """Batched EH lookup: hash -> directory slot -> bucket probe.
+
+    keys: (N,) uint32 or (2, N) words; directory: (D,) int32;
+    bucket_keys/vals: (C, words * S).  Returns (N,) uint32 values
+    (0xFFFFFFFF on miss), (2, N) at 64-bit values."""
+    from repro.core.hashing import dir_slot, hash_dir
+
+    def rows_of(key):
+        b = directory[dir_slot(hash_dir(key), global_depth)]
+        return bucket_keys[b], bucket_vals[b]
+
+    return _probe_ref(keys, rows_of, bucket_keys.shape[1],
+                      bucket_vals.shape[1])
 
 
 def shortcut_lookup_ref(keys, view_keys, view_vals,
                         global_depth) -> jax.Array:
     """One-indirection variant: slot arithmetic + direct view probe."""
-    from repro.core.extendible_hashing import (EMPTY_KEY, MISS, dir_slot,
-                                               hash_dir, hash_bucket)
-    S = view_keys.shape[1]
+    from repro.core.hashing import dir_slot, hash_dir
 
-    def one(key):
+    def rows_of(key):
         slot = dir_slot(hash_dir(key), global_depth)
-        row_k = view_keys[slot]
-        row_v = view_vals[slot]
-        start = hash_bucket(key) % jnp.uint32(S)
-        pos = ((start + jnp.arange(S, dtype=jnp.uint32))
-               % jnp.uint32(S)).astype(jnp.int32)
-        probed = row_k[pos]
-        hit = probed == key
-        empties = probed == EMPTY_KEY
-        before = jnp.cumsum(empties.astype(jnp.int32)) \
-            - empties.astype(jnp.int32)
-        live = hit & (before == 0)
-        found = jnp.any(live)
-        return jnp.where(found, row_v[pos[jnp.argmax(live)]], MISS)
+        return view_keys[slot], view_vals[slot]
 
-    return jax.vmap(one)(keys.astype(jnp.uint32))
+    return _probe_ref(keys, rows_of, view_keys.shape[1], view_vals.shape[1])
 
 
 def ragged_copy_ref(view, pool, slots, offsets) -> jax.Array:
