@@ -23,7 +23,7 @@ def run(scale: float = 1.0 / 64):
         keys = unique_keys(rng, n_keys)
         st = eh.eh_create(max_global_depth=leaves_log2 + 2,
                           bucket_slots=4, capacity=1 << (leaves_log2 + 1))
-        st, _ = eh.eh_insert_many(
+        st, _, _ = eh.eh_insert_many(
             st, jnp.asarray(keys),
             jnp.asarray(np.arange(n_keys, dtype=np.uint32)))
         g = int(st.global_depth)

@@ -317,13 +317,16 @@ def eh_insert_many(st: EHState, keys: jax.Array, values: jax.Array):
     each winning.  Keys absent from ``st`` then run through ``eh_insert``
     one at a time, in batch order (splits serialize them by nature); a
     split moves the overwritten values along with their rows.  Returns
-    ``(state, fresh)``: ``fresh`` is the number of absent keys, the
-    sequential loop's trip count."""
+    ``(state, fresh, touched)``: ``fresh`` is the number of absent keys,
+    the sequential loop's trip count; ``touched`` is the ``(capacity,)``
+    bool mask of the buckets the batch's keys map to in the returned
+    state, not in ``st``: a key a split moved counts in its new
+    bucket."""
     keys = keys.astype(jnp.uint32)
     values = values.astype(jnp.uint32)
     n = keys.shape[-1]
     if n == 0:
-        return st, jnp.zeros((), jnp.int32)
+        return st, jnp.zeros((), jnp.int32), jnp.zeros((st.capacity,), bool)
     b, slot, present, ahead = _classify(st, keys)
     idx = jnp.arange(n, dtype=jnp.int32)
 
@@ -363,7 +366,11 @@ def eh_insert_many(st: EHState, keys: jax.Array, values: jax.Array):
 
     _, st = jax.lax.while_loop(lambda c: c[0] < fresh, body,
                                (jnp.int32(0), st))
-    return st, fresh
+    slots = dir_slot(hash_dir(hashing.words(keys, st.key_words)),
+                     st.global_depth)
+    touched = jnp.zeros((st.capacity,), bool).at[st.directory[slots]].set(
+        True)
+    return st, fresh, touched
 
 
 def _answer(found, vals_row: jax.Array, idx, value_words: int):

@@ -332,6 +332,10 @@ class ShardedShortcutEH:
         return sum(s.keys_scanned for s in self.shards)
 
     @property
+    def buckets_touched(self) -> int:
+        return sum(s.buckets_touched for s in self.shards)
+
+    @property
     def split_seconds(self) -> float:
         """Host seconds spent splitting 64-bit keys and values into
         words and joining answers from them; 0 at 32 bits."""

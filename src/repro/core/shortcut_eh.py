@@ -117,6 +117,11 @@ class ShortcutEH:
         # sequential loop (absent)
         self.keys_in_place = 0
         self.keys_scanned = 0
+        # buckets in the touched sets handed to update requests
+        self.buckets_touched = 0
+        # the directory's depth as the last insert left it, kept on the
+        # host so an insert reads the device once
+        self._global_depth = int(self.state.global_depth)
         self.mapper = ShortcutMapper(
             replay_create=self._replay_create,
             replay_update=self._replay_update,
@@ -272,19 +277,21 @@ class ShortcutEH:
         maintenance (the paper's main-thread behaviour).
 
         Profiler spans: ``insert.scan`` (``eh_insert_many``, waited for
-        by the depth reads), ``insert.lock`` (the wait for the mapper's
-        lock), ``insert.publish``, ``insert.touched``,
+        by its one device read), ``insert.lock`` (the wait for the
+        mapper's lock), ``insert.publish``, ``insert.touched``,
         ``insert.submit``; at 64 bits first ``insert.split``."""
         keys = self._to_device(keys, self.key_bits, "key", "insert.split")
         values = self._to_device(values, self.value_bits, "value",
                                  "insert.split")
         with TraceAnnotation("insert.scan"):
-            old_g = int(self.state.global_depth)
+            old_g = self._global_depth
             with _held(self.mapper.lock, "insert.lock"):
-                self.state, fresh = eh.eh_insert_many(self.state, keys,
-                                                      values)
-                new_g, fresh = map(int, jax.device_get(
-                    (self.state.global_depth, fresh)))
+                self.state, fresh, touched = eh.eh_insert_many(
+                    self.state, keys, values)
+                new_g, fresh, touched = jax.device_get(
+                    (self.state.global_depth, fresh, touched))
+                new_g, fresh = int(new_g), int(fresh)
+                self._global_depth = new_g
                 self.keys_scanned += fresh
                 self.keys_in_place += keys.shape[-1] - fresh
                 versions = self.mapper.record([GLOBAL_VIEW])
@@ -306,10 +313,8 @@ class ShortcutEH:
                 self.mapper.submit_create([GLOBAL_VIEW], versions)
         else:
             with TraceAnnotation("insert.touched"):
-                slots = eh.dir_slot(
-                    eh.hash_dir(hashing.words(keys, self.state.key_words)),
-                    self.state.global_depth)
-                touched = np.unique(np.asarray(self.state.directory[slots]))
+                touched = np.flatnonzero(touched).astype(np.int32)
+                self.buckets_touched += touched.size
             with TraceAnnotation("insert.submit", version=versions[0]):
                 self.mapper.submit_update([GLOBAL_VIEW], versions,
                                           payload=touched)

@@ -91,7 +91,7 @@ class TestCrossOracle:
         ht = bl.ht_insert_many(bl.ht_create(12), kj, vj)
         hti = bl.hti_insert_many(bl.hti_create(12), kj, vj)
         ch = bl.ch_insert_many(bl.ch_create(6, 512), kj, vj)
-        ehs, _ = eh.eh_insert_many(
+        ehs, _, _ = eh.eh_insert_many(
             eh.eh_create(10, 8, 1024), kj, vj)
         a = np.asarray(bl.ht_lookup_many(ht, kj))
         b = np.asarray(bl.hti_lookup_many(hti, kj))

@@ -40,8 +40,9 @@ class TestLookup:
     def test_overwrite_updates_value(self, rng):
         keys = unique_keys(rng, 50)
         st_ = build(keys, np.arange(50, dtype=np.uint32))
-        st_, _ = eh.eh_insert_many(st_, jnp.asarray(keys[:10]),
-                                   jnp.asarray(np.full(10, 999, np.uint32)))
+        st_, _, _ = eh.eh_insert_many(
+            st_, jnp.asarray(keys[:10]),
+            jnp.asarray(np.full(10, 999, np.uint32)))
         out = np.asarray(eh.eh_lookup_many(st_, jnp.asarray(keys[:10])))
         assert (out == 999).all()
         # no double-count
@@ -62,7 +63,7 @@ class TestInvariants:
                              capacity=512)
         depths = []
         for i in range(0, 600, 100):
-            state, _ = eh.eh_insert_many(
+            state, _, _ = eh.eh_insert_many(
                 state, jnp.asarray(keys[i:i + 100]),
                 jnp.asarray(np.arange(i, i + 100, dtype=np.uint32)))
             depths.append(int(state.global_depth))
@@ -94,7 +95,7 @@ class TestShortcutView:
         st0 = build(keys[:200], np.arange(200, dtype=np.uint32))
         g0 = int(st0.global_depth)
         vk, vv = eh.compose_shortcut(st0, 1 << g0)
-        st1, _ = eh.eh_insert_many(
+        st1, _, _ = eh.eh_insert_many(
             st0, jnp.asarray(keys[200:]),
             jnp.asarray(np.arange(200, 400, dtype=np.uint32)))
         if int(st1.global_depth) != g0:
@@ -247,8 +248,8 @@ def test_insert_many_matches_sequential_scan(rng, case):
     vals = rng.integers(0, 2**32 - 1, keys.size, dtype=np.uint32)
     want = sequential_insert_many(base, jnp.asarray(keys),
                                   jnp.asarray(vals))
-    got, fresh = eh.eh_insert_many(base, jnp.asarray(keys),
-                                   jnp.asarray(vals))
+    got, fresh, _ = eh.eh_insert_many(base, jnp.asarray(keys),
+                                      jnp.asarray(vals))
     for name, a, b in zip(eh.EHState._fields, got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=name)
@@ -284,3 +285,105 @@ def test_insert_many_temporaries_stay_tiled():
     mem = eh.eh_insert_many.lower(state, batch, batch).compile() \
         .memory_analysis()
     assert mem.temp_size_in_bytes < 64 * 2**20, mem.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# The touched mask eh_insert_many returns, against the host's recompute
+# on the returned state.
+# ---------------------------------------------------------------------------
+
+def _distinct(rng, n, bits):
+    """``n`` distinct keys of ``bits`` bits, no word 0 or all ones."""
+    w = rng.integers(1, 2**32 - 1, (2, 2 * n), dtype=np.uint64)
+    k = np.unique(w[0] if bits == 32 else (w[0] << np.uint64(32)) | w[1])
+    return rng.permutation(k)[:n].astype(hashing.word_type(bits))
+
+
+def _device(keys, bits):
+    return jnp.asarray(keys if bits == 32 else hashing.split_words(keys))
+
+
+def _host_slots(keys, bits, g):
+    h = hashing.hash_dir_np(keys, bits)
+    return (h >> np.uint64(32 - g)).astype(np.int64) if g else \
+        np.zeros(h.size, np.int64)
+
+
+def _touched_present_with_repeats(rng, base, loaded, bits):
+    batch = rng.choice(loaded, 200)
+    batch[rng.choice(200, 40, replace=False)] = loaded[0]
+    return batch
+
+
+def _touched_split_without_doubling(rng, base, loaded, bits):
+    """Fresh keys into the shallowest bucket, as many on each side of
+    its split bit as fill that side: it splits once, and the directory
+    stays as it is."""
+    g = int(base.global_depth)
+    nb = int(base.num_buckets)
+    depth = np.asarray(base.local_depth[:nb])
+    b = int(np.argmin(depth))
+    l = int(depth[b])
+    assert l < g
+    directory = np.asarray(base.directory)
+
+    def side(keys):
+        return (hashing.hash_dir_np(keys, bits) >> np.uint64(31 - l)) & 1
+
+    stored = eh.host_keys(base)[b]
+    stored = stored[stored != hashing.all_ones(bits)]
+    cand = _distinct(rng, 20000, bits)
+    cand = cand[~np.isin(cand, loaded)]
+    cand = cand[directory[_host_slots(cand, bits, g)] == b]
+    s = base.bucket_slots
+    batch = np.concatenate([
+        cand[side(cand) == v][:s - int((side(stored) == v).sum())]
+        for v in (0, 1)])
+    assert batch.size > s - stored.size
+    return rng.permutation(batch)
+
+
+def _touched_doubling(rng, base, loaded, bits):
+    return _distinct(rng, 600, bits)
+
+
+def _touched_empty(rng, base, loaded, bits):
+    return np.zeros(0, hashing.word_type(bits))
+
+
+TOUCHED_CASES = {
+    "present_with_repeats": _touched_present_with_repeats,
+    "split_without_doubling": _touched_split_without_doubling,
+    "doubling": _touched_doubling,
+    "empty": _touched_empty,
+}
+
+
+@pytest.mark.parametrize("case", TOUCHED_CASES)
+@pytest.mark.parametrize("bits", [32, 64])
+def test_insert_many_touched_is_the_host_recompute(rng, bits, case):
+    """``eh_insert_many``'s mask holds exactly the buckets the batch's
+    keys map to in the state it returns: what the host found by hashing
+    the batch again, splits and moved rows included."""
+    base = eh.eh_create(9, 8, 512, bits, bits)
+    loaded = _distinct(rng, 300, bits)
+    base, _, _ = eh.eh_insert_many(base, _device(loaded, bits),
+                                   _device(loaded, bits))
+    keys = TOUCHED_CASES[case](rng, base, loaded, bits)
+    got, fresh, touched = eh.eh_insert_many(base, _device(keys, bits),
+                                            _device(keys, bits))
+    touched = np.asarray(touched)
+    assert touched.shape == (base.capacity,) and touched.dtype == bool
+    g = int(got.global_depth)
+    want = np.unique(np.asarray(got.directory)[_host_slots(keys, bits, g)])
+    np.testing.assert_array_equal(np.flatnonzero(touched), want)
+    if case == "present_with_repeats":
+        assert int(fresh) == 0
+        assert int(got.num_buckets) == int(base.num_buckets)
+    if case == "split_without_doubling":
+        assert g == int(base.global_depth)
+        assert int(got.num_buckets) == int(base.num_buckets) + 1
+    if case == "doubling":
+        assert g > int(base.global_depth)
+    if case == "empty":
+        assert not touched.any()

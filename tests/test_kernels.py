@@ -155,7 +155,7 @@ class TestEHKernels:
         keys = unique_keys(rng, n)
         st = eh.eh_create(max_global_depth=9, bucket_slots=slots,
                           capacity=1024)
-        st, _ = eh.eh_insert_many(
+        st, _, _ = eh.eh_insert_many(
             st, jnp.asarray(keys), jnp.asarray(np.arange(n, dtype=np.uint32)))
         D = 1 << int(st.global_depth)
         probe = np.concatenate(
@@ -178,7 +178,7 @@ class TestEHKernels:
             keys = unique_keys(rng, 150 + 40 * s)
             st = eh.eh_create(max_global_depth=8, bucket_slots=8,
                               capacity=256)
-            st, _ = eh.eh_insert_many(
+            st, _, _ = eh.eh_insert_many(
                 st, jnp.asarray(keys),
                 jnp.asarray(np.arange(keys.size, dtype=np.uint32)))
             states.append(st)
@@ -250,7 +250,7 @@ class TestEHKernels:
         keys = unique_keys(rng, 500)
         st = eh.eh_create(max_global_depth=8, bucket_slots=16,
                           capacity=512)
-        st, _ = eh.eh_insert_many(
+        st, _, _ = eh.eh_insert_many(
             st, jnp.asarray(keys),
             jnp.asarray(np.arange(500, dtype=np.uint32)))
         D = 1 << int(st.global_depth)

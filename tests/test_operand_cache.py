@@ -355,7 +355,7 @@ def _stacked_shards(rng, n_shards, keys_per_shard=160):
         k = unique_keys(rng, keys_per_shard)
         v = (np.arange(keys_per_shard, dtype=np.uint32)
              + np.uint32(s * 10_000))
-        st, _ = eh.eh_insert_many(st, jnp.asarray(k), jnp.asarray(v))
+        st, _, _ = eh.eh_insert_many(st, jnp.asarray(k), jnp.asarray(v))
         vs = max(1, 1 << int(st.global_depth))
         vk, vv = eh.compose_shortcut(st, vs)
         states.append(st)

@@ -1,11 +1,14 @@
 """Sharded shortcut runtime (core/sharded_eh + runtime/shard_group):
 oracle parity across shard counts, per-shard invariants, shard-local
 maintenance, and MapperGroup independence."""
+import math
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import extendible_hashing as eh
+from repro.core import hashing
 from repro.core.sharded_eh import (ShardedShortcutEH, partition_by_shard,
                                    shard_of_keys)
 from repro.core.shortcut_eh import ShortcutEH
@@ -111,6 +114,79 @@ class TestInsertCounters:
         if num_shards > 1:
             assert sum(s.keys_scanned > 0 for s in idx.shards) > 1
         idx.close()
+
+
+class TestTouchedBuckets:
+    """``ShortcutEH.insert`` hands ``submit_update`` the touched set
+    ``eh_insert_many`` returns: the one the host used to find by hashing
+    the batch again against the new state."""
+
+    @staticmethod
+    def _host_touched(state, keys, bits):
+        words = jnp.asarray(keys if bits == 32 else
+                            hashing.split_words(keys))
+        slots = eh.dir_slot(eh.hash_dir(hashing.words(words, bits // 32)),
+                            state.global_depth)
+        return np.unique(np.asarray(state.directory[slots]))
+
+    def _spy(self, shard, bits, got, want):
+        insert, submit = shard.insert, shard.mapper.submit_update
+        last = {}
+
+        def spied_insert(keys, values):
+            last["keys"] = keys
+            insert(keys, values)
+
+        def spied_submit(views, versions, payload=None):
+            got.append(payload)
+            want.append(self._host_touched(shard.state, last["keys"], bits))
+            submit(views, versions, payload=payload)
+
+        shard.insert = spied_insert
+        shard.mapper.submit_update = spied_submit
+
+    @pytest.mark.parametrize("bits", [32, 64])
+    @pytest.mark.parametrize("num_shards", [1, 4])
+    def test_payload_and_counter(self, rng, bits, num_shards):
+        kt = hashing.word_type(bits)
+        keys = np.unique(rng.integers(1, 2**bits - 2, 1200, dtype=kt,
+                                      endpoint=False))[:500]
+        keys = rng.permutation(keys)
+        got, want = [], []
+        with ShardedShortcutEH(10, 8, 1024, num_shards=num_shards,
+                               key_bits=bits, value_bits=bits) as idx:
+            for shard in idx.shards:
+                self._spy(shard, bits, got, want)
+            table = {}
+            batches = [keys[:300],                          # load
+                       rng.choice(keys[:300], 400),         # present
+                       np.concatenate([rng.choice(keys[:300], 100),
+                                       keys[300:]])]        # mixed
+            for batch in batches:
+                vals = rng.integers(0, 2**bits - 1, batch.size, dtype=kt)
+                idx.insert(batch, vals)
+                table.update(zip(batch.tolist(), vals.tolist()))
+                idx.pump()
+                assert idx.in_sync()
+            assert len(got) >= num_shards          # the present batch
+            for g, w in zip(got, want):
+                assert g.dtype == np.int32
+                np.testing.assert_array_equal(g, w)
+            assert idx.buckets_touched == sum(g.size for g in got)
+            assert idx.buckets_touched == sum(s.buckets_touched
+                                              for s in idx.shards)
+            probe = np.concatenate([keys, rng.integers(
+                2**(bits - 1), 2**bits - 2, 100, dtype=kt)])
+            miss = hashing.all_ones(bits)
+            expect = np.asarray([table.get(int(k), miss) for k in probe],
+                                kt)
+            for threshold in (math.inf, 0.0):      # shortcut, traditional
+                for shard in idx.shards:
+                    shard.fan_in_threshold = threshold
+                sc0 = idx.routed_shortcut
+                np.testing.assert_array_equal(
+                    np.asarray(idx.lookup_batched(probe)), expect)
+                assert (idx.routed_shortcut > sc0) == (threshold > 0)
 
 
 class TestShardLocality:

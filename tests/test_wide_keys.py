@@ -69,16 +69,16 @@ def loaded():
     keys = wide(rng, 450)
     vals = wide(rng, 450)
     base = eh.eh_create(*GEOMETRY, key_bits=64, value_bits=64)
-    st, fresh = eh.eh_insert_many(base, device_words(keys[:400]),
-                                  device_words(vals[:400]))
+    st, fresh, _ = eh.eh_insert_many(base, device_words(keys[:400]),
+                                     device_words(vals[:400]))
     assert int(fresh) == 400
     table = dict(zip(keys[:400].tolist(), vals[:400].tolist()))
     batch = np.concatenate([rng.choice(keys[:400], 150), keys[400:]])
     batch[rng.choice(150, 30, replace=False)] = keys[0]          # repeats
     bvals = rng.integers(0, MISS64, batch.size, dtype=np.uint64)
     mid = st
-    st, fresh = eh.eh_insert_many(st, device_words(batch),
-                                  device_words(bvals))
+    st, fresh, _ = eh.eh_insert_many(st, device_words(batch),
+                                     device_words(bvals))
     assert int(fresh) == 50
     table.update(zip(batch.tolist(), bvals.tolist()))
     return rng, keys, table, base, mid, st, batch, bvals
@@ -307,11 +307,11 @@ def test_32_bit_results_and_state_are_unchanged():
                       replace=False)
     vals = rng.integers(0, 2**32 - 1, 1000, dtype=np.uint32)
     st = eh.eh_create(10, 16, 512)
-    st, f1 = eh.eh_insert_many(st, jnp.asarray(keys[:700]),
-                               jnp.asarray(vals[:700]))
+    st, f1, _ = eh.eh_insert_many(st, jnp.asarray(keys[:700]),
+                                  jnp.asarray(vals[:700]))
     batch = np.concatenate([rng.choice(keys[:700], 300), keys[700:800]])
     bv = rng.integers(0, 2**32 - 1, batch.size, dtype=np.uint32)
-    st, f2 = eh.eh_insert_many(st, jnp.asarray(batch), jnp.asarray(bv))
+    st, f2, _ = eh.eh_insert_many(st, jnp.asarray(batch), jnp.asarray(bv))
     assert (int(f1), int(f2)) == (700, 100)
     assert _digest(*st[:8]) == "20b2ebe8759f96ef"
     probe = jnp.asarray(np.concatenate(
