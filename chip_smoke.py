@@ -2,9 +2,10 @@
 """Bring-up smoke of the sharded Shortcut-EH index on one TPU.
 
 Drives the index's main path once through its public entry points
-(``ShardedShortcutEH`` and ``ShortcutEH.lookup``) at the fused kernels'
-size cap — 16 shards, 64-slot buckets, directories of 2^14 slots, 2^19
-distinct keys made from ``--seed`` — and compares every answer exactly
+(``ShardedShortcutEH.insert`` and ``lookup_batched``) at the fused
+kernels' size cap — 16 shards, 64-slot buckets, directories of 2^14
+slots, 2^19 distinct keys made from ``--seed`` — and compares every
+answer exactly
 with a plain host oracle (sorted numpy arrays, independent of the
 package):
 
@@ -12,8 +13,7 @@ package):
       ``lookup_batched`` takes the traditional kernel;
   (b) pump half of the shards: mixed gates take the per-shard routed
       kernel;
-  (c) pump all shards: ``lookup_batched`` takes the shortcut kernel and
-      each shard's own ``lookup`` the stacked-view kernel;
+  (c) pump all shards: ``lookup_batched`` takes the shortcut kernel;
   (d) a short load into an index with the mapper threads on, then
       ``wait_in_sync``.
 
@@ -181,25 +181,10 @@ def run(args) -> None:
             shard.pump()
         lookup_phase(idx, "b", "sharded_routed_lookup", N // 2, N - N // 2)
 
-        # (c) pump all: the shortcut kernel, then each shard's own lookup
+        # (c) pump all: the shortcut kernel
         idx.pump()
         check(idx.in_sync(), "index not in sync after pump()")
         lookup_phase(idx, "c", "sharded_shortcut_lookup", N, 0)
-        t0 = time.perf_counter()
-        load_sid = idx.shard_of(load_keys)
-        miss_sid = idx.shard_of(miss_keys)
-        per = min(2048, int(np.bincount(load_sid, minlength=N).min()),
-                  int(np.bincount(miss_sid, minlength=N).min()))
-        for s, shard in enumerate(idx.shards):
-            q = batch(load_keys[load_sid == s], miss_keys[miss_sid == s], per)
-            sc0 = shard.routed_shortcut
-            got = np.asarray(shard.lookup(q))
-            check((got == oracle(q)).all(),
-                  f"shard {s} lookup differs from the oracle")
-            check(shard.routed_shortcut == sc0 + 1,
-                  f"shard {s} lookup did not take the shortcut")
-        print(f"phase c: stacked_shortcut_lookup: {N} shards x {2 * per} "
-              f"keys match the oracle; {time.perf_counter() - t0:.2f} s")
         check(idx.wait_in_sync(), "wait_in_sync() returned False")
         t0 = time.perf_counter()
         inv = idx.check_invariants()
@@ -212,7 +197,6 @@ def run(args) -> None:
         trad = idx.operands.handle("eh_trad")
         view = idx.operands.handle("eh_view")
         k2 = jnp.zeros((N, 256), jnp.uint32)
-        k1 = jnp.zeros((256,), jnp.uint32)
         flags = jnp.zeros((N,), jnp.int32)
         lowered = {
             "sharded_eh_lookup": kernels.sharded_eh_lookup.lower(k2, *trad),
@@ -220,8 +204,6 @@ def run(args) -> None:
                 k2, *trad, *view, flags),
             "sharded_shortcut_lookup":
                 kernels.sharded_shortcut_lookup.lower(k2, *view),
-            "stacked_shortcut_lookup":
-                kernels.stacked_shortcut_lookup.lower(k1, *view, 0),
         }
         for name, low in lowered.items():
             check("tpu_custom_call" in low.as_text(),

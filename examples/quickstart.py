@@ -9,7 +9,7 @@ one level up, on a paged KV cache.
 import numpy as np
 
 from repro.compile_cache import enable_compile_cache
-from repro.core.shortcut_eh import ShortcutEH
+from repro.core.sharded_eh import ShardedShortcutEH
 
 
 def main():
@@ -19,26 +19,28 @@ def main():
             + 1).astype(np.uint32)
     vals = np.arange(5000, dtype=np.uint32)
 
-    # the index: traditional directory (authoritative, synchronous) +
-    # shortcut directory (async replica, hardware-friendly access path)
-    with ShortcutEH(max_global_depth=12, bucket_slots=64, capacity=4096,
-                    async_mapper=True) as index:
+    # the index (one shard: the paper's flat index): traditional
+    # directory (authoritative, synchronous) + shortcut directory (async
+    # replica, hardware-friendly access path)
+    with ShardedShortcutEH(12, 64, 4096, num_shards=1,
+                           async_mapper=True) as index:
+        shard = index.shards[0]
         index.insert(keys[:4000], vals[:4000])
         print(f"inserted 4000; versions (trad, shortcut) = "
-              f"{index.versions()}  in_sync={index.in_sync()}")
+              f"{shard.versions()}  in_sync={index.in_sync()}")
 
         # lookups are correct immediately — routed via the traditional
         # path until the mapper catches up
-        out = np.asarray(index.lookup(keys[:1000]))
+        out = np.asarray(index.lookup_batched(keys[:1000]))
         assert (out == vals[:1000]).all()
         print(f"lookup wave 1 ok; routed shortcut? "
               f"{index.routed_shortcut > 0}")
 
         assert index.wait_in_sync()
-        print(f"mapper caught up; versions = {index.versions()}  "
+        print(f"mapper caught up; versions = {shard.versions()}  "
               f"avg fan-in = {index.avg_fan_in():.2f}")
 
-        out = np.asarray(index.lookup(keys[:4000]))
+        out = np.asarray(index.lookup_batched(keys[:4000]))
         assert (out == vals[:4000]).all()
         print(f"lookup wave 2 ok; routed shortcut? "
               f"{index.routed_shortcut > 0}")
@@ -47,10 +49,10 @@ def main():
         index.insert(keys[4000:], vals[4000:])
         print(f"after burst: in_sync={index.in_sync()} "
               f"(lookups keep working via the traditional path)")
-        out = np.asarray(index.lookup(keys))
+        out = np.asarray(index.lookup_batched(keys))
         assert (out == vals).all()
         assert index.wait_in_sync()
-        print(f"resynced: {index.versions()}; "
+        print(f"resynced: {shard.versions()}; "
               f"maintenance stats: {index.stats}")
 
 

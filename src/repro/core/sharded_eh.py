@@ -29,9 +29,9 @@ What sharding buys (DESIGN.md §4):
     gates disagree resolve in the same dispatch through the per-shard
     routed kernel (``sharded_routed_lookup``).
 
-``num_shards=1`` degenerates to the flat index: same hash, same routing
-law, same maintenance protocol, and ``lookup`` delegates straight to the
-inner :class:`ShortcutEH`.
+``num_shards=1`` is the flat index, the paper's own: the same class, the
+same operand cache and the same ``lookup_batched``, at a leading shard
+axis of 1.
 
 What it does not buy: a smaller per-shard view.  Each shard's
 directory and composed view keep ``2**global_depth`` rows, of which only
@@ -69,6 +69,9 @@ from repro.runtime.shard_group import (MapperGroup, pad_batch,
 __all__ = ["ShardedShortcutEH", "partition_by_shard", "shard_of_keys",
            "shard_order"]
 
+# key tile of the lookup kernels (a multiple of the 128 lanes)
+_TILE = 256
+
 
 def shard_of_keys(keys: np.ndarray, shard_bits: int,
                   key_bits: int = 32) -> np.ndarray:
@@ -97,7 +100,7 @@ def _trad_parts(states):
 
 
 class ShardedShortcutEH:
-    """N-way partitioned Shortcut-EH behind the flat index's API.
+    """Shortcut-EH partitioned N ways; ``num_shards=1`` is the flat index.
 
     Each shard's ``capacity``/``max_global_depth``/``bucket_slots`` equal
     the constructor arguments (capacity is per shard — sizing it as the
@@ -117,8 +120,7 @@ class ShardedShortcutEH:
                  capacity: int, *, num_shards: int = 1,
                  fan_in_threshold: float = 8.0,
                  poll_interval: float = 0.025, async_mapper: bool = False,
-                 routing_factory=None, key_bits: int = 32,
-                 value_bits: int = 32):
+                 key_bits: int = 32, value_bits: int = 32):
         if num_shards < 1 or num_shards & (num_shards - 1):
             raise ValueError(f"num_shards must be a power of two, "
                              f"got {num_shards}")
@@ -128,18 +130,6 @@ class ShardedShortcutEH:
         self._key_type = hashing.word_type(key_bits)
         self._value_type = hashing.word_type(value_bits)
         self._split_seconds = 0.0           # lookup_batched's own
-        self.shards = [
-            ShortcutEH(max_global_depth, bucket_slots, capacity,
-                       fan_in_threshold=fan_in_threshold,
-                       poll_interval=poll_interval,
-                       async_mapper=async_mapper,
-                       routing=(routing_factory(i) if routing_factory
-                                else None),
-                       key_bits=key_bits, value_bits=value_bits)
-            for i in range(num_shards)]
-        self.group = MapperGroup(
-            [s.mapper for s in self.shards],
-            router=lambda key: int(self.shard_of([key])[0]))
         # primary storage of the stacked lookup operands (families
         # "eh_view" / "eh_trad", DESIGN.md §4.4): replays publish their
         # shard's slice straight into the stack at publish time, so the
@@ -147,8 +137,17 @@ class ShardedShortcutEH:
         # zero device work in steady state, and per-shard views exist
         # only as memoized slices of the stack (no duplicates)
         self.operands = StackedOperandCache(num_shards)
-        for i, s in enumerate(self.shards):
-            s.bind_operand_cache(self.operands, i)
+        self.shards = [
+            ShortcutEH(max_global_depth, bucket_slots, capacity,
+                       self.operands, i,
+                       fan_in_threshold=fan_in_threshold,
+                       poll_interval=poll_interval,
+                       async_mapper=async_mapper,
+                       key_bits=key_bits, value_bits=value_bits)
+            for i in range(num_shards)]
+        self.group = MapperGroup(
+            [s.mapper for s in self.shards],
+            router=lambda key: int(self.shard_of([key])[0]))
 
     # -- routing -------------------------------------------------------------
 
@@ -189,32 +188,7 @@ class ShardedShortcutEH:
                 idx = order[starts[s]:starts[s] + c]
                 self.shards[s].insert(keys[idx], values[idx])
 
-    def lookup(self, keys) -> jax.Array:
-        """Routed lookup in input order (each shard independently takes
-        its shortcut or traditional path per its own gate).
-
-        Cross-shard batching: one argsort pass, static padded per-shard
-        sub-batches (pad lanes are dropped on scatter-back)."""
-        keys = self._keys(keys)
-        if keys.size == 0:
-            return self._answer(np.empty(0, self._value_type))
-        if self.num_shards == 1:
-            return self.shards[0].lookup(keys)
-        sid = self.shard_of(keys)
-        order, counts, starts = shard_order(sid, self.num_shards)
-        cap = pad_batch(int(counts.max()))
-        padded, counts, order, rank = partition_by_shard(
-            keys, sid, self.num_shards, cap,
-            order=order, counts=counts, starts=starts)
-        results = np.empty((self.num_shards, cap), self._value_type)
-        for s in range(self.num_shards):
-            if counts[s]:
-                results[s] = np.asarray(self.shards[s].lookup(padded[s]))
-        out = np.empty(keys.size, self._value_type)
-        out[order] = results[sid[order], rank]
-        return self._answer(out)
-
-    def lookup_batched(self, keys, *, tile: int = 256) -> jax.Array:
+    def lookup_batched(self, keys) -> jax.Array:
         """Fused cross-shard lookup: ONE Pallas dispatch for all shards,
         fed from the device-resident operand cache.
 
@@ -286,14 +260,14 @@ class ShardedShortcutEH:
             keys_dev = jnp.asarray(padded)
             if n_sc == len(involved):
                 res = sharded_shortcut_lookup(keys_dev, *view_ops,
-                                              tile=tile)
+                                              tile=_TILE)
             elif n_sc == 0:
-                res = sharded_eh_lookup(keys_dev, *trad_ops, tile=tile)
+                res = sharded_eh_lookup(keys_dev, *trad_ops, tile=_TILE)
             else:
                 flags = jnp.asarray(
                     [0 if ok else 1 for ok in shortcut_ok], jnp.int32)
                 res = sharded_routed_lookup(keys_dev, *trad_ops,
-                                            *view_ops, flags, tile=tile)
+                                            *view_ops, flags, tile=_TILE)
         with TraceAnnotation("lookup.wait"):
             res = np.asarray(res)
         if self.value_bits == 64:

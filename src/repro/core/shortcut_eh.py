@@ -10,14 +10,16 @@ Architecture (faithful to the paper):
     25 ms) / synchronous ``pump()``, create-collapses-older-updates
     batching, eager ``block_until_ready`` population, version gating and
     fan-in routing — is the *generic* shortcut-maintenance runtime
-    (``runtime/mapper.ShortcutMapper``, DESIGN.md §4).  This class supplies
-    only the two replay callables:
+    (``runtime/mapper.ShortcutMapper``, DESIGN.md §4).  This class, one
+    shard of ``core/sharded_eh.ShardedShortcutEH``, supplies only the two
+    replay callables:
       - ``update`` replay remaps the view slots of touched buckets
         (``rewiring.remap_slots``);
       - ``create`` replay rebuilds the whole view after a directory
         doubling (``extendible_hashing.compose_shortcut``).
-  * Lookups route through the shortcut only when it is in sync *and* the
-    average fan-in is at most ``fan_in_threshold`` (paper: 8) — below that
+  * Lookups (``ShardedShortcutEH.lookup_batched``, the only lookup) route
+    a shard through the shortcut only when it is in sync *and* the
+    average fan-in is at most ``fan_in_threshold`` (paper: 8) — above it
     the TLB-thrashing analogue (a virtual footprint of 2^g pages vs 2^g
     pointers + m pages) makes the traditional path cheaper
     (:class:`~repro.runtime.mapper.FanInRouting`).
@@ -74,44 +76,41 @@ def _held(lock, span: str):
 
 
 class ShortcutEH:
-    """Thin client of the shortcut-maintenance runtime for the EH index.
+    """One shard of the index: its extendible-hashing state, its mapper
+    and its replays.  :class:`~repro.core.sharded_eh.ShardedShortcutEH`
+    builds the shards and answers every lookup; this class has no lookup.
 
-    ``async_mapper=True`` runs the paper's mapper thread; tests and
-    deterministic benchmarks use ``async_mapper=False`` + :meth:`pump`.
-    A custom ``routing`` policy (e.g.
-    :class:`~repro.runtime.mapper.HysteresisRouting`) may replace the
-    default fan-in threshold rule.
+    The shard's composed view lives only in ``operands`` (a
+    :class:`~repro.runtime.operand_cache.StackedOperandCache`), as row
+    ``shard`` of its ``"eh_view"`` family: replays publish into it,
+    and :meth:`view_snapshot` reads a memoised slice of it.  ``insert``
+    keeps the ``"eh_trad"`` family's row warm once a lookup built it.
+
+    ``async_mapper=True`` runs the paper's mapper thread; tests use
+    ``async_mapper=False`` + :meth:`pump`.
 
     ``key_bits``/``value_bits`` (32 or 64) fix the width of keys and
     values.  At 64 bits the host splits each key and value into its two
-    uint32 words on the way in and joins the words of each answer on the
-    way out (``split_seconds`` times both); ``lookup`` then answers with
-    a numpy uint64 array.  An array whose dtype is wider than the width
-    is refused, never wrapped.
+    uint32 words on the way in (``split_seconds`` times it).  An array
+    whose dtype is wider than the width is refused, never wrapped.
     """
 
     def __init__(self, max_global_depth: int, bucket_slots: int,
-                 capacity: int, *, fan_in_threshold: float = 8.0,
+                 capacity: int, operands, shard: int, *,
+                 fan_in_threshold: float = 8.0,
                  poll_interval: float = 0.025, async_mapper: bool = False,
-                 routing=None, key_bits: int = 32, value_bits: int = 32):
+                 key_bits: int = 32, value_bits: int = 32):
         self.state = eh.eh_create(max_global_depth, bucket_slots, capacity,
                                   key_bits, value_bits)
         self.key_bits, self.value_bits = key_bits, value_bits
         # host seconds spent splitting 64-bit keys and values into words
-        # and joining answers from them (0 at 32 bits)
+        # (0 at 32 bits)
         self.split_seconds = 0.0
-        # The composed view is ONE atomically-swapped tuple
-        # (view_keys, view_vals, view_log2): replays publish a fully
-        # built tuple and readers snapshot it once, so a reader racing
-        # an async replay can never pair new keys with old vals.
-        # When bound to a StackedOperandCache (bind_operand_cache), the
-        # stack owns the view instead and _view stays None — per-shard
-        # reads become memoized slices of the stack (DESIGN.md §4.4).
-        self._view: Optional[tuple] = None
-        self._cache = None                  # StackedOperandCache or None
-        self._shard = 0
+        self._cache = operands
+        self._shard = int(shard)
         self._vfam = "eh_view"
         self._tfam = "eh_trad"
+        self._memo = None         # (stack slice, view) of view_snapshot
         # insert's keys by how eh_insert_many took them: overwritten in
         # place (present when their batch began) or run through the
         # sequential loop (absent)
@@ -127,11 +126,11 @@ class ShortcutEH:
             replay_update=self._replay_update,
             snapshot=lambda: self.state,
             view_arrays=self._view_arrays,
-            routing=routing or FanInRouting(float(fan_in_threshold)),
+            routing=FanInRouting(float(fan_in_threshold)),
             poll_interval=poll_interval, async_mapper=async_mapper,
             name="eh-mapper")
 
-    # -- delegated bookkeeping (kept for API compatibility) ------------------
+    # -- delegated bookkeeping ----------------------------------------------
 
     @property
     def stats(self) -> MaintenanceStats:
@@ -146,14 +145,6 @@ class ShortcutEH:
         return self.mapper.routed_fallback
 
     @property
-    def trad_version(self) -> int:
-        return self.mapper.trad_version(GLOBAL_VIEW)
-
-    @property
-    def sc_version(self) -> int:
-        return self.mapper.sc_version(GLOBAL_VIEW)
-
-    @property
     def fan_in_threshold(self):
         return self.mapper.threshold
 
@@ -161,16 +152,12 @@ class ShortcutEH:
     def fan_in_threshold(self, value: float) -> None:
         self.mapper.threshold = value
 
-    @property
-    def poll_interval(self) -> float:
-        return self.mapper.poll_interval
-
     # -- publish epochs (operand-cache keys; runtime/operand_cache.py) -------
     #
     # state_epoch moves with every ``self.state`` reassignment (insert
     # stores the new state, then ``record()`` bumps under the same
-    # lock); view_epoch with every replay-batch publication of
-    # ``self._view`` (bumped by the runtime before sc_version, so a
+    # lock); view_epoch with every replay-batch publication of the
+    # shard's view (bumped by the runtime before sc_version, so a
     # version gate can never certify a view the cache still sees as
     # clean-but-old).  Read the epoch BEFORE snapshotting the arrays.
 
@@ -182,67 +169,26 @@ class ShortcutEH:
     def view_epoch(self) -> int:
         return self.mapper.view_epoch
 
-    # -- operand-cache binding (inverted ownership, DESIGN.md §4.4) ----------
+    # -- the shard's view ---------------------------------------------------
 
-    def bind_operand_cache(self, cache, shard: int, *,
-                           view_family: str = "eh_view",
-                           trad_family: str = "eh_trad") -> None:
-        """Hand view ownership to a stacked operand cache.
-
-        After binding, replays publish straight into the owning shard's
-        slice of the stacked ``view_family`` (at the mapper's
-        ``next_view_epoch``, before ``sc_version`` moves), inserts keep
-        ``trad_family`` warm once a lookup built it, and every per-shard
-        view read is a memoized slice of the stack — the local ``_view``
-        duplicate is deleted.  Bind before any maintenance is enqueued
-        (``ShardedShortcutEH`` binds at construction)."""
-        self._cache = cache
-        self._shard = int(shard)
-        self._vfam = view_family
-        self._tfam = trad_family
-        self._view = None        # the stack is the primary storage now
-        self._bound_memo = None
-
-    def _bound_view(self) -> Optional[tuple]:
-        """(view_keys, view_vals, view_log2) slices of the stack, or
-        None before this shard's first publication.  view_keys/vals are
-        padded to the stacked extent; rows past ``2**view_log2`` are
-        never indexed (the lookup slots by the shard's own log2).
-        Memoized on the cache's slice identity, so the device->host
-        ``view_log2`` read happens once per publish, not per lookup."""
+    def view_snapshot(self) -> Optional[tuple]:
+        """One consistent (view_keys, view_vals, view_log2), or None
+        before this shard's first publication: slices of the stack.
+        view_keys/vals are padded to the stacked extent; rows past
+        ``2**view_log2`` are never indexed (the lookup slots by the
+        shard's own log2).  Memoised on the cache's slice identity, so
+        the device->host ``view_log2`` read happens once a publish, not
+        once a read."""
         pub = self._cache.published(self._vfam)
         if pub is None or not pub[self._shard]:
             return None
         sl = self._cache.slice_of(self._vfam, self._shard)
-        memo = self._bound_memo
+        memo = self._memo
         if memo is not None and memo[0] is sl:
             return memo[1]
         view = (sl[0], sl[1], int(sl[2]))
-        self._bound_memo = (sl, view)
+        self._memo = (sl, view)
         return view
-
-    # -- view snapshot (atomic read; see _view comment in __init__) ----------
-
-    def view_snapshot(self) -> Optional[tuple]:
-        """One consistent (view_keys, view_vals, view_log2) or None."""
-        if self._cache is not None:
-            return self._bound_view()
-        return self._view
-
-    @property
-    def view_keys(self) -> Optional[jax.Array]:
-        v = self.view_snapshot()
-        return None if v is None else v[0]
-
-    @property
-    def view_vals(self) -> Optional[jax.Array]:
-        v = self.view_snapshot()
-        return None if v is None else v[1]
-
-    @property
-    def view_log2(self) -> int:
-        v = self.view_snapshot()
-        return -1 if v is None else v[2]
 
     # -- the host's words ----------------------------------------------------
 
@@ -257,18 +203,6 @@ class ShortcutEH:
             words = hashing.split_words(np.asarray(a))
             self.split_seconds += time.perf_counter() - t
         return jnp.asarray(words)
-
-    def _to_host(self, res: jax.Array, span: str):
-        """Answers as the caller gets them: as they are at 32-bit values,
-        joined into a numpy uint64 array at 64."""
-        if self.value_bits == 32:
-            return res
-        res = np.asarray(res)
-        with TraceAnnotation(span):
-            t = time.perf_counter()
-            out = hashing.join_words(res)
-            self.split_seconds += time.perf_counter() - t
-        return out
 
     # -- main-thread API ----------------------------------------------------
 
@@ -295,18 +229,17 @@ class ShortcutEH:
                 self.keys_scanned += fresh
                 self.keys_in_place += keys.shape[-1] - fresh
                 versions = self.mapper.record([GLOBAL_VIEW])
-                if self._cache is not None:
-                    # keep the stacked traditional family warm at
-                    # publish (write) time — but only once a lookup
-                    # actually built it; a shortcut-routed steady state
-                    # never pays for (or holds) the traditional stack
-                    st = self.state
-                    with TraceAnnotation("insert.publish"):
-                        self._cache.publish_if_present(
-                            self._tfam, self._shard,
-                            lambda: (st.directory, st.bucket_keys,
-                                     st.bucket_vals, st.global_depth),
-                            epoch=self.mapper.trad_epoch)
+                # keep the stacked traditional family warm at
+                # publish (write) time — but only once a lookup
+                # actually built it; a shortcut-routed steady state
+                # never pays for (or holds) the traditional stack
+                st = self.state
+                with TraceAnnotation("insert.publish"):
+                    self._cache.publish_if_present(
+                        self._tfam, self._shard,
+                        lambda: (st.directory, st.bucket_keys,
+                                 st.bucket_vals, st.global_depth),
+                        epoch=self.mapper.trad_epoch)
         if new_g != old_g:
             # doubling: the runtime pops outdated updates before the create
             with TraceAnnotation("insert.submit", version=versions[0]):
@@ -318,40 +251,6 @@ class ShortcutEH:
             with TraceAnnotation("insert.submit", version=versions[0]):
                 self.mapper.submit_update([GLOBAL_VIEW], versions,
                                           payload=touched)
-
-    def lookup(self, keys) -> jax.Array:
-        """Route through the shortcut when in sync and fan-in permits."""
-        keys = self._to_device(keys, self.key_bits, "key", "lookup.split")
-        return self._to_host(self._lookup(keys), "lookup.split")
-
-    def _lookup(self, keys: jax.Array) -> jax.Array:
-        # gate FIRST, snapshot after: a replay landing in between
-        # publishes a strictly newer view, which the gate's verdict
-        # still covers; snapshotting first would let the gate certify
-        # a stale tuple (async mode could then serve pre-insert data)
-        use = self.mapper.gate(self.avg_fan_in(), [GLOBAL_VIEW])
-        view = self.view_snapshot()   # single read: the swap is atomic
-        use = use and view is not None
-        self.mapper.count_route(use)
-        if use:
-            if self._cache is not None and jax.default_backend() == "tpu":
-                # resolve straight off the stacked primary: the kernel
-                # block-selects the shard via scalar prefetch, so no
-                # per-shard slice is ever materialized on device
-                from repro.kernels.eh_lookup import stacked_shortcut_lookup
-                ops = self._cache.handle(self._vfam)
-                return stacked_shortcut_lookup(keys, *ops, self._shard)
-            # the tuple's own view_log2, never the live global_depth: a
-            # doubling after the snapshot would index past the view.
-            # Bound mode pays nothing extra here: view_snapshot is the
-            # cache's memoized slice of the stack (zero device work in
-            # steady state; the slice cost was paid at publish time).
-            return eh.shortcut_lookup_many(view[0], view[1], view[2], keys)
-        return eh.eh_lookup_many(self.state, keys)
-
-    def use_shortcut(self) -> bool:
-        return (self.view_snapshot() is not None
-                and self.mapper.gate(self.avg_fan_in(), [GLOBAL_VIEW]))
 
     def in_sync(self) -> bool:
         return self.mapper.in_sync([GLOBAL_VIEW])
@@ -377,26 +276,18 @@ class ShortcutEH:
     # -- replay callables (the only EH-specific maintenance code) ------------
 
     def _view_arrays(self):
-        if self._cache is not None:
-            # the stacked family IS the published object readers get
-            return self._cache.handle(self._vfam) or ()
-        view = self._view
-        return () if view is None else view[:2]
+        # the stacked family IS the published object readers get
+        return self._cache.handle(self._vfam) or ()
 
     def _publish_view(self, vk, vv, vlog2: int) -> None:
-        """Publish one replayed view: bound mode writes the owning
-        shard's slice of the stack at the mapper's ``next_view_epoch``
-        (zero-copy publish — this runs on the mapper thread, before
-        ``sc_version`` moves; a view grown past the stacked extent
-        triggers the cache's background re-stack); standalone mode is
-        the classic atomic tuple swap."""
-        if self._cache is not None:
-            self._cache.publish(
-                self._vfam, self._shard,
-                (vk, vv, jnp.asarray(vlog2, jnp.int32)),
-                epoch=self.mapper.next_view_epoch)
-            return
-        self._view = (vk, vv, vlog2)
+        """Publish one replayed view into the owning shard's row of the
+        stack at the mapper's ``next_view_epoch`` (this runs on the
+        mapper thread, before ``sc_version`` moves; a view grown past
+        the stacked extent triggers the cache's re-stack)."""
+        self._cache.publish(
+            self._vfam, self._shard,
+            (vk, vv, jnp.asarray(vlog2, jnp.int32)),
+            epoch=self.mapper.next_view_epoch)
 
     def _replay_create(self, st: eh.EHState, requests) -> None:
         g = int(st.global_depth)
@@ -429,13 +320,12 @@ class ShortcutEH:
             stale = np.isin(dir_np, touched)
             slots = np.nonzero(stale)[0].astype(np.int32)
         if slots.size == 0:
-            if self._cache is not None:
-                # no stale slots, but the reader is still owed an epoch:
-                # this _process will bump view_epoch and publish its
-                # sc versions, and the entry must never lag a
-                # gate-certified version
-                self._cache.touch(self._vfam, self._shard,
-                                  epoch=self.mapper.next_view_epoch)
+            # no stale slots, but the reader is still owed an epoch:
+            # this _process will bump view_epoch and publish its
+            # sc versions, and the entry must never lag a
+            # gate-certified version
+            self._cache.touch(self._vfam, self._shard,
+                              epoch=self.mapper.next_view_epoch)
             return
         with TraceAnnotation("mapper.remap"):
             n = _pad_chunk(slots.size)
@@ -448,10 +338,3 @@ class ShortcutEH:
                                       offsets_p)
             self._publish_view(vk, vv, vlog2)
         self.mapper.stats.slots_remapped += int(slots.size)
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.close()
-        return False

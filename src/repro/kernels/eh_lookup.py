@@ -14,17 +14,12 @@ tile) with one shared body, :func:`_resolve_tile`:
   * :func:`sharded_eh_lookup` / :func:`sharded_shortcut_lookup` — the
     partitioned index (``core/sharded_eh.py``): per-shard structures
     stacked on a leading shard axis, the shard loop a grid dimension, so
-    N shards share one kernel specialization.  :func:`eh_lookup` and
-    :func:`shortcut_lookup` are their N=1 case.
+    N shards share one kernel specialization.  The flat index is their
+    N=1 case.
   * :func:`sharded_routed_lookup` — per-shard routed: a scalar-prefetched
     ``two_level`` flag per shard picks the directory or the composed
     view *inside the same dispatch*; the flag is uniform per grid cell,
     so each cell runs exactly one ``pl.when`` arm of the shared body.
-  * :func:`stacked_shortcut_lookup` — the flat (single-shard) path
-    against the stacked primary operand storage
-    (``runtime/operand_cache``, DESIGN.md §4.4): the shard index arrives
-    by scalar prefetch and the block index maps select that shard's
-    block of the ``(N, V, S)`` stack; no per-shard slice is materialized.
 
 How a key tile resolves on the chip (DESIGN.md §2.4).  One shard's bucket
 pools (or composed view) are VMEM-resident for the whole shard: the block
@@ -156,14 +151,6 @@ def _routed_kernel(sc_ref, keys_ref, dir_ref, bk_ref, bv_ref, vk_ref,
                       out_ref, *scratch)
 
 
-def _stacked_select_kernel(sc_ref, keys_ref, vk_ref, vv_ref, out_ref,
-                           *scratch):
-    """One key-tile grid cell against the shard block that the index maps
-    selected with ``sc_ref[0]``; ``sc_ref[1]`` is that shard's view log2."""
-    _resolve_tile(keys_ref, sc_ref[1], None, vk_ref, vv_ref, out_ref,
-                  *scratch)
-
-
 def _lanes(pool) -> int:
     """A pool row's lanes in VMEM: padded to whole 128-lane rows."""
     return -(-pool.shape[-1] // _LANES) * _LANES
@@ -181,16 +168,14 @@ def _vmem_limit(pools, tile: int) -> int:
                resident + gather + tiles + _VMEM_MARGIN)
 
 
-def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
+def _call(kernel, scalars, keys, directory, pools, *, tile: int,
           interpret: Optional[bool]):
-    """What every entry point calls: ONE ``pallas_call`` over grid (key
-    rows, key tiles).
+    """What every entry point calls: ONE ``pallas_call`` over grid
+    (shards, key tiles).
 
-    keys (B, n), or (B, 2, n) words; directory (N, D) or None; pools:
-    (N, R, words * S) arrays, key and value pools alternating;
-    ``select(b, sc)`` maps grid row b (and the scalar-prefetch ref) to
-    the shard block its pages come from.  Returns (B, n) uint32, or
-    (B, 2, n) at 64-bit values."""
+    keys (N, n), or (N, 2, n) words; directory (N, D) or None; pools:
+    (N, R, words * S) arrays, key and value pools alternating.  Returns
+    (N, n) uint32, or (N, 2, n) at 64-bit values."""
     if tile % _LANES:
         raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
     n = keys.shape[-1]
@@ -208,7 +193,7 @@ def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
 
     def per_shard(block, **kw):
         return pl.BlockSpec((None,) + block,
-                            lambda b, i, sc: (select(b, sc), 0, 0),
+                            lambda b, i, sc: (b, 0, 0),
                             pipeline_mode=pl.Buffered(1), **kw)
 
     in_specs, args = [key_spec], [keys]
@@ -246,42 +231,9 @@ def _call(kernel, scalars, keys, directory, pools, *, select, tile: int,
     return out[:, 0, :n] if vw == 1 else out[..., :n]
 
 
-def _by_row(b, sc):
-    return b
-
-
 # ---------------------------------------------------------------------------
-# Single-shard entry points (N=1 case of the sharded kernel).
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def eh_lookup(keys, directory, bucket_keys, bucket_vals, global_depth, *,
-              tile: int = 256, interpret: Optional[bool] = None):
-    """Traditional EH lookup: keys (K,) -> values (K,) (MISS on absent);
-    64-bit keys or values as (2, K) words.
-
-    directory: (D,) int32; bucket_keys/vals: (C, words * S) uint32."""
-    return sharded_eh_lookup(
-        keys[None], directory[None], bucket_keys[None], bucket_vals[None],
-        jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
-        tile=tile, interpret=interpret)[0]
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def shortcut_lookup(keys, view_keys, view_vals, global_depth, *,
-                    tile: int = 256, interpret: Optional[bool] = None):
-    """Shortcut lookup over the composed view: one indirection fewer.
-
-    view_keys/vals: (2^g_cap, words * S) — slot-indexed bucket pages."""
-    return sharded_shortcut_lookup(
-        keys[None], view_keys[None], view_vals[None],
-        jnp.reshape(jnp.asarray(global_depth, jnp.int32), (1,)),
-        tile=tile, interpret=interpret)[0]
-
-
-# ---------------------------------------------------------------------------
-# Batched cross-shard entry points (``core/sharded_eh.py``): one dispatch,
-# one specialization, shard = grid dimension.
+# Entry points (``core/sharded_eh.py``): one dispatch, one specialization,
+# shard = grid dimension.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -297,7 +249,7 @@ def sharded_eh_lookup(keys, directories, bucket_keys, bucket_vals,
     (N, K) uint32, or (N, 2, K) at 64-bit values."""
     return _call(functools.partial(_lookup_kernel, two_level=True),
                  global_depths.astype(jnp.int32), keys, directories,
-                 (bucket_keys, bucket_vals), select=_by_row, tile=tile,
+                 (bucket_keys, bucket_vals), tile=tile,
                  interpret=interpret)
 
 
@@ -308,28 +260,8 @@ def sharded_shortcut_lookup(keys, view_keys, view_vals, global_depths, *,
     """Shortcut lookup across N stacked shards (views (N, V, S))."""
     return _call(functools.partial(_lookup_kernel, two_level=False),
                  global_depths.astype(jnp.int32), keys, None,
-                 (view_keys, view_vals), select=_by_row, tile=tile,
+                 (view_keys, view_vals), tile=tile,
                  interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def stacked_shortcut_lookup(keys, view_keys, view_vals, view_log2s,
-                            shard, *, tile: int = 256,
-                            interpret: Optional[bool] = None):
-    """Single-shard shortcut lookup resolved straight off the stacked
-    primary storage (``runtime/operand_cache``, DESIGN.md §4.4).
-
-    keys: (K,) or (2, K); view_keys/vals: the full (N, V, words * S)
-    stacks; view_log2s:
-    (N,); ``shard`` selects which block the grid reads — via scalar
-    prefetch, so all shards (and all shard *indices*) share one compiled
-    specialization, and the flat per-shard lookup path needs no device
-    copy of its shard's view."""
-    sidx = jnp.asarray(shard, jnp.int32)
-    scalars = jnp.stack([sidx, view_log2s.astype(jnp.int32)[sidx]])
-    return _call(_stacked_select_kernel, scalars, keys[None], None,
-                 (view_keys, view_vals), select=lambda b, sc: sc[0],
-                 tile=tile, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -358,4 +290,4 @@ def sharded_routed_lookup(keys, directories, bucket_keys, bucket_vals,
                          view_log2s.astype(jnp.int32)])        # (3, N)
     return _call(_routed_kernel, scalars, keys, directories,
                  (bucket_keys, bucket_vals, view_keys, view_vals),
-                 select=_by_row, tile=tile, interpret=interpret)
+                 tile=tile, interpret=interpret)

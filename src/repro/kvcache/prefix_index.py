@@ -33,7 +33,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core import hashing
-from repro.core.shortcut_eh import ShortcutEH
+from repro.core.sharded_eh import ShardedShortcutEH
 from repro.runtime.mapper import (GLOBAL_VIEW, FragmentationRouting,
                                   ShortcutMapper)
 
@@ -63,9 +63,9 @@ class PrefixCacheIndex:
                  async_mapper: bool = False, table_log2: int = 8,
                  chain_threshold: float = 2.0):
         self.block_size = block_size
-        self.index = ShortcutEH(
-            max_global_depth=max_global_depth, bucket_slots=bucket_slots,
-            capacity=capacity, async_mapper=async_mapper)
+        self.index = ShardedShortcutEH(
+            max_global_depth, bucket_slots, capacity, num_shards=1,
+            async_mapper=async_mapper)
         self.hits = 0
         self.misses = 0
         # -- prefix -> block-table shortcut (third runtime client) ----------
@@ -131,7 +131,7 @@ class PrefixCacheIndex:
                 self.hits += 1
                 return len(blocks) * self.block_size, list(blocks)
         self.prefix_mapper.count_route(False)
-        vals = np.asarray(self.index.lookup(keys))
+        vals = np.asarray(self.index.lookup_batched(keys))
         blocks = []
         for v in vals:
             if int(v) == _MISS:

@@ -159,8 +159,7 @@ class ShortcutKVManager:
         # get_context) or a memoized slice of it (per-shard snapshot /
         # replay read-modify-write) — no per-shard duplicates exist.
         self.operands = StackedOperandCache(num_shards)
-        self.views = ShardViewRegistry(num_shards, cache=self.operands,
-                                       family="kv_view")
+        self.views = ShardViewRegistry(self.operands, family="kv_view")
         zk = jnp.zeros((L, self.seqs_per_shard, seq_capacity, KV, hd),
                        cache.k_pool.dtype)
         zv = jnp.zeros_like(zk)

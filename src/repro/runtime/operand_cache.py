@@ -18,8 +18,7 @@ fix-up work at all (cf. Utopia's restrictive mappings, PAPERS.md).
     Writers — mapper replay threads, the KV view registry — call
     :meth:`StackedOperandCache.publish` from the *mapper thread* at
     publish time, **before** ``sc_version`` is published: one
-    ``dynamic_update_slice`` per part, donated in place on accelerator
-    backends.
+    ``dynamic_update_slice`` per part.
   * The lookup path (:meth:`get` with no ``parts``) is an epoch
     comparison plus a handle return — zero device work in steady state.
   * Per-shard reads (``view_snapshot``, a replay's read-modify-write)
@@ -52,16 +51,9 @@ state lives client-side (the "eh_trad" bucket arrays): ``get`` with a
 ``lookup_refreshes``), and the client may keep the family warm
 afterwards with :meth:`publish_if_present` at mutation time.
 
-Donation/aliasing rules (DESIGN.md §4.3/§4.4): with ``donate=True`` the
-publish donates the previous stacked buffer to the update-slice call on
-accelerator backends, so XLA patches it in place instead of allocating a
-sibling copy.  Donation deletes the old buffer, which makes every
-returned stack a **loan** whose lifetime ends at the next publish — only
-safe when a single thread drives lookups.  It is therefore opt-in; the
-default never donates and is safe for concurrent readers (old loans and
-memoized slices stay valid until released).  CPU donation would be a
-warn-and-copy no-op either way, so the interpret-mode tests cannot
-exercise the donating path.
+A publish writes a new stack and leaves the old one alive, so a handle
+or memoized slice that a concurrent reader holds stays valid until it is
+released (DESIGN.md §4.4).
 """
 from __future__ import annotations
 
@@ -75,12 +67,6 @@ import jax.numpy as jnp
 __all__ = ["StackedOperandCache", "OperandCacheStats"]
 
 
-def _backend_can_donate() -> bool:
-    """XLA implements input/output aliasing on the TPU only here; CPU
-    donation is a warn-and-copy no-op."""
-    return jax.default_backend() == "tpu"
-
-
 @jax.jit
 def _refresh_slice(stacked: jax.Array, part: jax.Array,
                    shard: jax.Array) -> jax.Array:
@@ -88,13 +74,6 @@ def _refresh_slice(stacked: jax.Array, part: jax.Array,
     so N shards share one compiled variant per shape/dtype)."""
     start = (shard.astype(jnp.int32),) + (jnp.int32(0),) * part.ndim
     return jax.lax.dynamic_update_slice(stacked, part[None], start)
-
-
-# donating twin: same computation, previous stack buffer reused in place
-_refresh_slice_donated = jax.jit(
-    lambda stacked, part, shard: _refresh_slice.__wrapped__(
-        stacked, part, shard),
-    donate_argnums=(0,))
 
 
 @jax.jit
@@ -152,18 +131,12 @@ class StackedOperandCache:
     tuple, the writer stores arrays before epochs, and both stores are
     GIL-atomic, so a racing reader can only observe newer-arrays-than-
     epoch (a hit it was entitled to), never the reverse.
-
-    ``donate=True`` opts into in-place publishes on accelerator backends
-    (see the module docstring's aliasing rules): single-reader drivers
-    only — a donating publish deletes the buffers a concurrent reader
-    may still be about to dispatch with.
     """
 
-    def __init__(self, num_shards: int, *, donate: bool = False):
+    def __init__(self, num_shards: int):
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
         self.num_shards = num_shards
-        self.donate = bool(donate)
         self.stats = OperandCacheStats()
         self._entries: Dict[str, _Entry] = {}
         # identity-keyed per-(family, shard) slice memo: one slice copy
@@ -213,27 +186,18 @@ class StackedOperandCache:
                 return ent.arrays
             arrays = list(ent.arrays)
             new_epochs = list(ent.epochs)
-            refresh = self._refresh_fn()
-            try:
-                for s in dirty:
-                    p = tuple(parts(s))
-                    if (tuple(a.shape for a in p) != ent.part_shapes
-                            or tuple(a.dtype for a in p)
-                            != ent.part_dtypes):
-                        # shape changed (e.g. directory growth): restack
-                        return self._rebuild(family, epochs, parts,
-                                             prebuilt={s: p})
-                    sidx = jnp.int32(s)
-                    for j, a in enumerate(p):
-                        arrays[j] = refresh(arrays[j], a, sidx)
-                    new_epochs[s] = max(new_epochs[s], epochs[s])
-                    self.stats.lookup_refreshes += 1
-            except BaseException:
-                if refresh is _refresh_slice_donated:
-                    # the old buffers may already be donated away; drop
-                    # the entry so the next get rebuilds from scratch
-                    self._drop(family)
-                raise
+            for s in dirty:
+                p = tuple(parts(s))
+                if (tuple(a.shape for a in p) != ent.part_shapes
+                        or tuple(a.dtype for a in p) != ent.part_dtypes):
+                    # shape changed (e.g. directory growth): restack
+                    return self._rebuild(family, epochs, parts,
+                                         prebuilt={s: p})
+                sidx = jnp.int32(s)
+                for j, a in enumerate(p):
+                    arrays[j] = _refresh_slice(arrays[j], a, sidx)
+                new_epochs[s] = max(new_epochs[s], epochs[s])
+                self.stats.lookup_refreshes += 1
             # commit arrays before epochs, only once every dirty slice
             # refreshed — a parts() exception mid-loop must not leave
             # the entry claiming freshness over the old arrays
@@ -282,21 +246,15 @@ class StackedOperandCache:
                 self._restack_grow(family, ent, shapes)
             parts = tuple(self._pad_to_extent(a, ext)
                           for a, ext in zip(parts, ent.part_shapes))
-            refresh = self._refresh_fn()
             arrays = list(ent.arrays)
             sidx = jnp.int32(shard)
-            try:
-                for j, a in enumerate(parts):
-                    arrays[j] = refresh(arrays[j], a, sidx)
-            except BaseException:
-                if refresh is _refresh_slice_donated:
-                    self._drop(family)
-                raise
+            for j, a in enumerate(parts):
+                arrays[j] = _refresh_slice(arrays[j], a, sidx)
             ent.arrays = tuple(arrays)     # arrays first, then epoch
             ent.published[shard] = True
             ent.epochs[shard] = max(ent.epochs[shard], int(epoch))
             self.stats.publish_refreshes += 1
-            # without donation each refresh writes a whole new stack
+            # each refresh writes a whole new stack
             self.stats.publish_bytes += sum(int(a.nbytes) for a in arrays)
 
     def publish_if_present(self, family: str, shard: int,
@@ -401,11 +359,6 @@ class StackedOperandCache:
         return family in self._entries
 
     # -- internals (call with self._lock held) -------------------------------
-
-    def _refresh_fn(self):
-        return (_refresh_slice_donated
-                if self.donate and _backend_can_donate()
-                else _refresh_slice)
 
     def _install(self, family: str, ent: _Entry) -> None:
         self._entries[family] = ent

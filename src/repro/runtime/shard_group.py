@@ -16,9 +16,10 @@ and (in async mode) threads, plus:
 
   * a **key → shard router** (client-supplied; Sharded-EH routes on the
     top bits of the directory hash, the KV manager on ``seq_id % N``);
-  * an optional :class:`ShardViewRegistry` — per-shard atomically-swapped
-    view tuples, so replay callables and ``view_arrays`` read the
-    registry instead of closing over whole-structure client attributes;
+  * an optional :class:`ShardViewRegistry` — per-shard views as rows of
+    a stacked operand family, so replay callables and ``view_arrays``
+    read the registry instead of closing over whole-structure client
+    attributes;
   * **aggregated** :class:`~repro.runtime.mapper.MaintenanceStats` and
     route counters across the group (per-shard stats remain available
     through each member); batch-level route decisions that span shards
@@ -47,7 +48,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import fields
-from typing import (Callable, Dict, Hashable, Iterable, List, Optional,
+from typing import (Callable, Dict, Hashable, Iterable, Optional,
                     Sequence)
 
 import numpy as np
@@ -107,109 +108,47 @@ def partition_by_shard(keys: np.ndarray, sid: np.ndarray, num_shards: int,
 
 
 class ShardViewRegistry:
-    """Per-shard, atomically-published shortcut view tuples.
+    """Per-shard facade of one stacked operand family.
 
-    Two storage modes behind one API:
+    The registry owns no arrays: ``cache`` (a
+    :class:`~repro.runtime.operand_cache.StackedOperandCache`) holds
+    every shard's view as a row of the stacked ``family``.
+    :meth:`publish` writes the shard's row straight into the stack at
+    the caller's client epoch (zero-copy publish, DESIGN.md §4.4) and
+    :meth:`snapshot` returns the cache's memoized slice of it.  A slice
+    tuple is drawn from ONE atomically-swapped stacked tuple, so a
+    reader can never pair arrays from two publications of a shard.
 
-    **Standalone** (``cache=None``): each slot holds ONE tuple of device
-    arrays (or ``None`` before the first publication).  :meth:`publish`
-    is a single list-item store and :meth:`snapshot` a single list-item
-    load — both atomic under the GIL — so a reader can never pair
-    arrays from two different publications of the same shard (the tear
-    the KV manager's old two-attribute ``view_k, view_v = ...``
-    publication allowed).
-
-    **Cache-backed** (``cache=`` a
-    :class:`~repro.runtime.operand_cache.StackedOperandCache`): the
-    registry stops owning any arrays and becomes a per-shard facade of
-    one stacked operand family — :meth:`publish` writes the shard's
-    slice straight into the stack at the caller-supplied client epoch
-    (zero-copy publish, DESIGN.md §4.4) and :meth:`snapshot` returns
-    the cache's memoized slice of it.  Tear-freedom carries over: a
-    slice tuple is drawn from ONE atomically-swapped stacked tuple.
-
-    Writer discipline (both modes): one writer per slot — the shard's
-    mapper thread (or the ``pump()`` caller in sync mode), enforced by
-    the mapper's per-shard replay mutex
-    (``ShortcutMapper._replay_mutex``).  That single-writer rule + the
-    atomic swap is exactly the ``ShortcutEH._view`` protocol, lifted to
-    N shards; no cross-shard lock exists and none is needed.
+    Writer discipline: one writer per shard — the shard's mapper thread
+    (or the ``pump()`` caller in sync mode), enforced by the mapper's
+    per-shard replay mutex (``ShortcutMapper._replay_mutex``); no
+    cross-shard lock exists and none is needed.
     """
 
-    def __init__(self, num_shards: int, *, cache=None,
-                 family: str = "kv_view"):
-        if num_shards < 1:
-            raise ValueError(f"num_shards must be >= 1, got {num_shards}")
-        self._n = num_shards
+    def __init__(self, cache, *, family: str = "kv_view"):
+        self._n = cache.num_shards
         self._cache = cache
         self._family = family
-        if cache is None:
-            self._views: List[Optional[tuple]] = [None] * num_shards
-            # publish epochs for the device-resident operand cache
-            # (runtime/operand_cache.py): bumped AFTER the tuple store,
-            # so a reader that reads the epoch first and snapshots
-            # second can at worst record a newer tuple under an older
-            # epoch — a redundant refresh next get(), never stale
-            self._epochs: List[int] = [0] * num_shards
-        elif cache.num_shards != num_shards:
-            raise ValueError(f"cache has {cache.num_shards} shards, "
-                             f"registry asked for {num_shards}")
 
     def __len__(self) -> int:
         return self._n
 
-    def publish(self, shard: int, arrays: Iterable, *,
-                epoch: Optional[int] = None) -> None:
-        """Publish shard ``shard``'s view tuple.
-
-        Standalone: atomic tuple swap, then bump the internal epoch
-        (writer order matters, see ``_epochs``); ``epoch`` is ignored.
-        Cache-backed: one donated ``dynamic_update_slice`` into the
-        stacked family at the client ``epoch`` (required — replays pass
-        their mapper's ``next_view_epoch``)."""
-        if self._cache is not None:
-            if epoch is None:
-                raise ValueError("cache-backed registry publications "
-                                 "must carry the client epoch")
-            self._cache.publish(self._family, shard, tuple(arrays),
-                                epoch=epoch)
-            return
-        self._views[shard] = tuple(arrays)
-        self._epochs[shard] += 1
-
-    def epoch(self, shard: int) -> int:
-        """Shard's publish epoch; read BEFORE :meth:`snapshot`."""
-        return self.epochs()[shard]
-
-    def epochs(self) -> List[int]:
-        """All shards' publish epochs (copied; read before snapshots)."""
-        if self._cache is not None:
-            eps = self._cache.epochs(self._family)
-            return [0] * self._n if eps is None else eps
-        return list(self._epochs)
+    def publish(self, shard: int, arrays: Iterable, *, epoch: int) -> None:
+        """Publish shard ``shard``'s view tuple: one
+        ``dynamic_update_slice`` into the stacked family at the client
+        ``epoch`` (replays pass their mapper's ``next_view_epoch``)."""
+        self._cache.publish(self._family, shard, tuple(arrays), epoch=epoch)
 
     def snapshot(self, shard: int) -> Optional[tuple]:
-        """One consistent view tuple (or None) — read the slot ONCE and
-        index the result; never re-read per array.  Cache-backed: the
-        memoized slice of the stack (zero device work in steady state)."""
-        if self._cache is not None:
-            return self._cache.slice_of(self._family, shard)
-        return self._views[shard]
-
-    def snapshot_all(self) -> list:
-        """Per-shard snapshots, each internally consistent (the list is
-        copied so concurrent publications don't mutate it underfoot)."""
-        return [self.snapshot(s) for s in range(self._n)]
+        """One consistent view tuple (or None): the memoized slice of
+        the stack (zero device work in steady state)."""
+        return self._cache.slice_of(self._family, shard)
 
     def arrays(self, shard: int) -> tuple:
-        """Population target for the runtime's ``view_arrays`` hook:
-        the shard's current arrays, or () before first publication.
-        Cache-backed: the stacked family itself — it IS the published
-        object the reader will be handed."""
-        if self._cache is not None:
-            return self._cache.handle(self._family) or ()
-        v = self._views[shard]
-        return () if v is None else v
+        """Population target for the runtime's ``view_arrays`` hook: the
+        stacked family itself — it IS the published object the reader
+        will be handed — or () before its first publication."""
+        return self._cache.handle(self._family) or ()
 
 
 class MapperGroup:

@@ -6,15 +6,19 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.eh_lookup import (eh_lookup, sharded_eh_lookup,
-                                     sharded_shortcut_lookup,
-                                     shortcut_lookup)
+from repro.kernels.eh_lookup import (sharded_eh_lookup,
+                                     sharded_shortcut_lookup)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ragged_copy import ragged_copy
 from repro.kernels.shortcut_attention import shortcut_attention
 
 from conftest import unique_keys
+
+
+def one(a):
+    """A single shard's operand with the kernels' leading axis of 1."""
+    return jnp.asarray(a)[None]
 
 
 def tol(dtype):
@@ -160,9 +164,9 @@ class TestEHKernels:
         D = 1 << int(st.global_depth)
         probe = np.concatenate(
             [keys, unique_keys(rng, 100, lo=2**31, hi=2**32 - 2)])
-        out = eh_lookup(jnp.asarray(probe), st.directory[:D],
-                        st.bucket_keys, st.bucket_vals, st.global_depth,
-                        tile=tile)
+        out = sharded_eh_lookup(one(probe), one(st.directory[:D]),
+                                one(st.bucket_keys), one(st.bucket_vals),
+                                one(st.global_depth), tile=tile)[0]
         want = ref.eh_lookup_ref(jnp.asarray(probe), st.directory[:D],
                                  st.bucket_keys, st.bucket_vals,
                                  st.global_depth)
@@ -170,8 +174,9 @@ class TestEHKernels:
 
     @pytest.mark.parametrize("N", [1, 4])
     def test_sharded_kernel_matches_per_shard(self, rng, N):
-        """One grid-over-shards dispatch == N independent single-shard
-        calls (the shard loop shares one pallas_call specialization)."""
+        """One grid-over-shards dispatch answers each shard as the jnp
+        reference does on that shard alone (the shard loop shares one
+        pallas_call specialization)."""
         from repro.core import extendible_hashing as eh
         states, probes = [], []
         for s in range(N):
@@ -197,9 +202,9 @@ class TestEHKernels:
                         jnp.int32), tile=128)
         D = states[0].directory.shape[0]
         for s, st in enumerate(states):
-            want = eh_lookup(jnp.asarray(padded[s]), st.directory[:D],
-                             st.bucket_keys, st.bucket_vals,
-                             st.global_depth, tile=128)
+            want = ref.eh_lookup_ref(jnp.asarray(padded[s]),
+                                     st.directory[:D], st.bucket_keys,
+                                     st.bucket_vals, st.global_depth)
             np.testing.assert_array_equal(np.asarray(out[s]),
                                           np.asarray(want))
         # shortcut flavour over shape-uniform composed views
@@ -212,8 +217,8 @@ class TestEHKernels:
             jnp.asarray([int(st.global_depth) for st in states],
                         jnp.int32), tile=128)
         for s, st in enumerate(states):
-            want = shortcut_lookup(jnp.asarray(padded[s]), *views[s],
-                                   st.global_depth, tile=128)
+            want = ref.shortcut_lookup_ref(jnp.asarray(padded[s]),
+                                           *views[s], st.global_depth)
             np.testing.assert_array_equal(np.asarray(out_sc[s]),
                                           np.asarray(want))
 
@@ -255,10 +260,11 @@ class TestEHKernels:
             jnp.asarray(np.arange(500, dtype=np.uint32)))
         D = 1 << int(st.global_depth)
         vk, vv = eh.compose_shortcut(st, D)
-        probe = jnp.asarray(keys)
-        trad = eh_lookup(probe, st.directory[:D], st.bucket_keys,
-                         st.bucket_vals, st.global_depth, tile=128)
-        short = shortcut_lookup(probe, vk, vv, st.global_depth, tile=128)
+        trad = sharded_eh_lookup(one(keys), one(st.directory[:D]),
+                                 one(st.bucket_keys), one(st.bucket_vals),
+                                 one(st.global_depth), tile=128)
+        short = sharded_shortcut_lookup(one(keys), one(vk), one(vv),
+                                        one(st.global_depth), tile=128)
         np.testing.assert_array_equal(np.asarray(trad), np.asarray(short))
 
 

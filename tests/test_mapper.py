@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.shortcut_eh import ShortcutEH
+from repro.core.sharded_eh import ShardedShortcutEH
 from repro.kvcache import paged_cache as pc
 from repro.kvcache.shortcut_cache import ShortcutKVManager
 from repro.runtime.mapper import (CREATE, GLOBAL_VIEW, UPDATE,
@@ -215,17 +215,18 @@ class TestAsyncEquivalence:
         vals = np.arange(300, dtype=np.uint32)
         results = {}
         for mode in ("pump", "async"):
-            with ShortcutEH(max_global_depth=8, bucket_slots=16,
-                            capacity=512, poll_interval=0.003,
-                            async_mapper=(mode == "async")) as sc:
+            with ShardedShortcutEH(8, 16, 512, num_shards=1,
+                                   poll_interval=0.003,
+                                   async_mapper=(mode == "async")) as sc:
                 for i in range(0, 300, 60):
                     sc.insert(keys[i:i + 60], vals[i:i + 60])
                 if mode == "pump":
                     sc.pump()
                 assert sc.wait_in_sync(timeout=30.0)
-                results[mode] = (np.array(sc.view_keys),
-                                 np.array(sc.view_vals),
-                                 sc.versions())
+                shard = sc.shards[0]
+                vk, vv, _ = shard.view_snapshot()
+                results[mode] = (np.array(vk), np.array(vv),
+                                 shard.versions())
         np.testing.assert_array_equal(results["pump"][0],
                                       results["async"][0])
         np.testing.assert_array_equal(results["pump"][1],
@@ -297,16 +298,17 @@ class TestRoutingPolicies:
 
     def test_eh_accepts_custom_routing(self, rng):
         keys = unique_keys(rng, 50)
-        sc = ShortcutEH(max_global_depth=8, bucket_slots=64, capacity=128,
-                        routing=HysteresisRouting(FanInRouting(6.0),
-                                                  FanInRouting(10.0)))
+        sc = ShardedShortcutEH(8, 64, 128, num_shards=1)
+        shard = sc.shards[0]
+        shard.mapper.routing = HysteresisRouting(FanInRouting(6.0),
+                                                 FanInRouting(10.0))
         sc.insert(keys, np.arange(50, dtype=np.uint32))
         sc.pump()
-        out = np.asarray(sc.lookup(keys))
+        out = np.asarray(sc.lookup_batched(keys))
         np.testing.assert_array_equal(out, np.arange(50, dtype=np.uint32))
-        assert sc.fan_in_threshold is None   # no scalar threshold to report
+        assert shard.fan_in_threshold is None  # no scalar threshold to report
         with pytest.raises(AttributeError):
-            sc.fan_in_threshold = 4.0
+            shard.fan_in_threshold = 4.0
 
 
 class TestClientParity:
@@ -315,24 +317,25 @@ class TestClientParity:
     and fallback reads agree."""
 
     def test_same_runtime_class(self, rng):
-        sc = ShortcutEH(max_global_depth=8, bucket_slots=16, capacity=64)
+        sc = ShardedShortcutEH(8, 16, 64, num_shards=1)
         cache = pc.cache_create(2, 64, 4, 2, 8, 4, 16, dtype=jnp.float32)
         mgr = ShortcutKVManager(cache, seq_capacity=64)
-        assert type(sc.mapper) is ShortcutMapper
+        assert type(sc.shards[0].mapper) is ShortcutMapper
         assert type(mgr.mapper) is ShortcutMapper
 
     def test_parity_stale_then_sync_then_agree(self, rng):
         # EH client
         keys = unique_keys(rng, 120)
-        sc = ShortcutEH(max_global_depth=8, bucket_slots=16, capacity=256)
+        sc = ShardedShortcutEH(8, 16, 256, num_shards=1)
         sc.insert(keys, np.arange(120, dtype=np.uint32))
         eh_stale = not sc.in_sync()
         sc.pump()
         from repro.core import extendible_hashing as eh
-        trad = np.asarray(eh.eh_lookup_many(sc.state, jnp.asarray(keys)))
+        st = sc.shards[0].state
+        vk, vv, _ = sc.shards[0].view_snapshot()
+        trad = np.asarray(eh.eh_lookup_many(st, jnp.asarray(keys)))
         short = np.asarray(eh.shortcut_lookup_many(
-            sc.view_keys, sc.view_vals, sc.state.global_depth,
-            jnp.asarray(keys)))
+            vk, vv, st.global_depth, jnp.asarray(keys)))
         # KV client
         cache = pc.cache_create(2, 64, 4, 2, 8, 4, 16, dtype=jnp.float32)
         mgr = ShortcutKVManager(cache, seq_capacity=64)
@@ -353,5 +356,5 @@ class TestClientParity:
         np.testing.assert_allclose(np.asarray(vp[:, :, :, :sl]),
                                    np.asarray(vs[:, :, :, :sl]))
         # both published their maintenance through the runtime stats
-        assert sc.mapper.stats.creates + sc.mapper.stats.updates >= 1
+        assert sc.stats.creates + sc.stats.updates >= 1
         assert mgr.mapper.stats.creates >= 1

@@ -3,9 +3,10 @@ routed fused lookup: publish/touch/seed semantics and the writer-order
 contract, pull-mode epoch/refresh/rebuild semantics, grow-past-extent
 re-stacks with live readers, routed-kernel parity for ``two_level``
 vectors in {all-true, all-false, mixed}, the empty-batch
-short-circuits, and cache coherence under concurrent async replays (no
-torn stacks; a slice older than the epoch the gate certified is never
-served; steady-state lookups patch zero slices)."""
+short-circuits, the per-shard view registry, and cache coherence under
+concurrent async replays (no torn stacks; a slice older than the epoch
+the gate certified is never served; steady-state lookups patch zero
+slices)."""
 import threading
 
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from repro.core import extendible_hashing as eh
 from repro.core.sharded_eh import ShardedShortcutEH
 from repro.kernels import eh_lookup as kmod
 from repro.runtime.operand_cache import StackedOperandCache
+from repro.runtime.shard_group import ShardViewRegistry
 
 from conftest import unique_keys
 
@@ -104,20 +106,6 @@ class TestCacheUnit:
         out = cache.get("f", [1, 1], self._parts(data))
         np.testing.assert_array_equal(np.asarray(out[0]),
                                       [[5, 5, 5], [1, 1, 1]])
-
-    def test_donate_flag_safe_on_cpu(self):
-        """donate=True falls back to the non-donating refresh off
-        accelerators; semantics are unchanged."""
-        cache = StackedOperandCache(2, donate=True)
-        data = [(np.zeros(3, np.int32),), (np.ones(3, np.int32),)]
-        old = cache.get("f", [0, 0], self._parts(data))
-        data[1] = (np.full(3, 9, np.int32),)
-        out = cache.get("f", [0, 3], self._parts(data))
-        np.testing.assert_array_equal(np.asarray(out[0]),
-                                      [[0, 0, 0], [9, 9, 9]])
-        # the pre-refresh loan is still readable (no donation on CPU)
-        np.testing.assert_array_equal(np.asarray(old[0]),
-                                      [[0, 0, 0], [1, 1, 1]])
 
     def test_epoch_arity_checked_and_invalidate(self):
         cache = StackedOperandCache(2)
@@ -396,23 +384,6 @@ class TestRoutedKernelParity:
             jnp.asarray(flags, jnp.int32), tile=128)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
-    def test_stacked_single_shard_select_matches_flat(self, rng):
-        """The bound single-shard path (``stacked_shortcut_lookup``):
-        scalar-prefetched shard id selects one slice of the stacked
-        views inside the kernel — parity with the flat per-shard
-        shortcut lookup, including misses, for every shard."""
-        o = _stacked_shards(rng, 4)
-        for s in range(4):
-            keys = jnp.concatenate([
-                o["keys"][s],
-                jnp.asarray(unique_keys(rng, 40, lo=2**31, hi=2**32 - 2),
-                            jnp.uint32)])
-            ref = eh.shortcut_lookup_many(
-                o["vks"][s], o["vvs"][s], int(o["vls"][s]), keys)
-            got = kmod.stacked_shortcut_lookup(
-                keys, o["vks"], o["vvs"], o["vls"], s, tile=128)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-
     def test_slot_width_mismatch_rejected(self, rng):
         o = _stacked_shards(rng, 2)
         with pytest.raises(ValueError, match="slot widths"):
@@ -420,6 +391,25 @@ class TestRoutedKernelParity:
                 o["keys"], o["dirs"], o["bks"], o["bvs"], o["gds"],
                 o["vks"][:, :, :4], o["vvs"][:, :, :4], o["vls"],
                 jnp.zeros(2, jnp.int32), tile=128)
+
+
+class TestShardViewRegistry:
+    def test_views_are_rows_of_the_stacked_family(self):
+        """The registry owns no arrays: a publish writes the shard's row
+        of the cache's family at the given epoch, a snapshot is the
+        cache's slice of that row, and the population target is the
+        stacked family itself."""
+        cache = StackedOperandCache(2)
+        views = ShardViewRegistry(cache, family="v")
+        assert len(views) == 2 and views.arrays(0) == ()
+        assert views.snapshot(1) is None
+        views.publish(1, (jnp.full((3,), 7, jnp.int32),), epoch=4)
+        assert cache.epochs("v") == [0, 4]
+        assert cache.published("v") == [False, True]
+        assert views.snapshot(1) is cache.slice_of("v", 1)
+        np.testing.assert_array_equal(np.asarray(views.snapshot(1)[0]),
+                                      [7, 7, 7])
+        assert views.arrays(0) is cache.handle("v")
 
 
 # ---------------------------------------------------------------------------
@@ -543,8 +533,8 @@ class TestCachedShardedLookup:
             stats = idx.operands.stats.snapshot()
             out = idx.lookup_batched(np.empty(0, np.uint32))
             assert out.shape == (0,) and out.dtype == jnp.uint32
-            out = idx.lookup(np.empty(0, np.uint32))
-            assert out.shape == (0,)
+            out = idx.lookup_batched([])              # a list, by value
+            assert out.shape == (0,) and out.dtype == jnp.uint32
             assert sum(counts.values()) == 0          # no dispatch at all
             assert (idx.routed_shortcut, idx.routed_traditional) == routed
             after = idx.operands.stats                # cache untouched

@@ -11,7 +11,6 @@ from repro.core import extendible_hashing as eh
 from repro.core import hashing
 from repro.core.sharded_eh import (ShardedShortcutEH, partition_by_shard,
                                    shard_of_keys)
-from repro.core.shortcut_eh import ShortcutEH
 from repro.runtime.mapper import (GLOBAL_VIEW, FanInRouting,
                                   ShortcutMapper)
 from repro.runtime.shard_group import MapperGroup
@@ -41,11 +40,11 @@ def _keys_for_shard(rng, shard, shard_bits, n):
 class TestOracleParity:
     @pytest.mark.parametrize("num_shards", [1, 2, 8])
     def test_matches_dict_and_flat_index(self, rng, num_shards):
-        """Same trace through a dict oracle, a flat ShortcutEH, and the
-        sharded index: results bit-for-bit identical at every step."""
+        """Same trace through a dict oracle, the flat (N=1) index, and
+        the sharded index: results bit-for-bit identical at every step."""
         keys, vals, misses = _mixed_trace(rng)
         oracle = {}
-        flat = ShortcutEH(12, 8, 2048)
+        flat = ShardedShortcutEH(12, 8, 2048, num_shards=1)
         sharded = ShardedShortcutEH(12, 8, 2048, num_shards=num_shards)
         step = 300
         for i in range(0, len(keys), step):
@@ -55,16 +54,16 @@ class TestOracleParity:
             sharded.insert(kb, vb)
             # probe BEFORE maintenance (stale views): traditional routes
             probe = np.concatenate([keys[:i + step], misses])
-            got = np.asarray(sharded.lookup(probe))
-            want = np.asarray(flat.lookup(probe))
+            got = np.asarray(sharded.lookup_batched(probe))
+            want = np.asarray(flat.lookup_batched(probe))
             np.testing.assert_array_equal(got, want)
             flat.pump()
             sharded.pump()
             assert sharded.in_sync()
             # probe AFTER maintenance (shortcut-eligible routes)
-            got = np.asarray(sharded.lookup(probe))
+            got = np.asarray(sharded.lookup_batched(probe))
             np.testing.assert_array_equal(got, np.asarray(
-                flat.lookup(probe)))
+                flat.lookup_batched(probe)))
             expect = np.asarray(
                 [oracle.get(int(k), 0xFFFFFFFF) for k in probe], np.uint32)
             np.testing.assert_array_equal(got, expect)
@@ -77,10 +76,15 @@ class TestOracleParity:
         sharded = ShardedShortcutEH(12, 8, 2048, num_shards=num_shards)
         sharded.insert(keys, vals)
         probe = np.concatenate([keys, misses])
-        # stale: traditional fused kernel resolves all shards
+        # stale: traditional fused kernel resolves all shards, each as
+        # the jnp reference does on that shard's state
         got = np.asarray(sharded.lookup_batched(probe))
-        np.testing.assert_array_equal(got, np.asarray(
-            sharded.lookup(probe)))
+        sid = sharded.shard_of(probe)
+        want = np.empty_like(got)
+        for s, shard in enumerate(sharded.shards):
+            want[sid == s] = np.asarray(eh.eh_lookup_many(
+                shard.state, jnp.asarray(probe[sid == s])))
+        np.testing.assert_array_equal(got, want)
         sharded.pump()
         # in sync: shortcut fused kernel (when views are shape-uniform)
         got = np.asarray(sharded.lookup_batched(probe))
@@ -221,7 +225,7 @@ class TestShardLocality:
             assert s1.creates == s1.updates == 0       # no replay at all
             assert s1.slots_remapped == 0
             # lookups for shard-0 keys are correct and shard 1 untouched
-            out = np.asarray(sharded.lookup(k0))
+            out = np.asarray(sharded.lookup_batched(k0))
             np.testing.assert_array_equal(
                 out, np.arange(len(k0), dtype=np.uint32))
 

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.shortcut_eh import ShortcutEH
+from repro.core.sharded_eh import ShardedShortcutEH
 
 from conftest import unique_keys
 
@@ -18,10 +18,10 @@ def test_mixed_workload_sync_cycle(rng):
     After each insert burst the shortcut is stale (lookups still correct
     via the traditional path); after maintenance it serves again."""
     keys = unique_keys(rng, 3000)
-    sc = ShortcutEH(max_global_depth=10, bucket_slots=16, capacity=2048)
+    sc = ShardedShortcutEH(10, 16, 2048, num_shards=1)
     sc.insert(keys[:2400], np.arange(2400, dtype=np.uint32))  # bulk load
     sc.pump()
-    assert sc.use_shortcut()
+    assert sc.in_sync() and sc.avg_fan_in() <= 8.0
 
     inserted = 2400
     for wave in range(4):
@@ -31,16 +31,20 @@ def test_mixed_workload_sync_cycle(rng):
         inserted += 150
         assert not sc.in_sync()            # stale immediately after burst
         lookups = keys[:inserted]
-        out = np.asarray(sc.lookup(lookups))  # routed traditional
+        tr0 = sc.routed_traditional
+        out = np.asarray(sc.lookup_batched(lookups))
+        assert sc.routed_traditional == tr0 + 1   # routed traditional
         np.testing.assert_array_equal(out, np.arange(inserted,
                                                      dtype=np.uint32))
         sc.pump()                          # mapper catches up
         assert sc.in_sync()
-        out = np.asarray(sc.lookup(lookups))  # routed shortcut again
+        sc0 = sc.routed_shortcut
+        out = np.asarray(sc.lookup_batched(lookups))
+        assert sc.routed_shortcut == sc0 + 1      # routed shortcut again
         np.testing.assert_array_equal(out, np.arange(inserted,
                                                      dtype=np.uint32))
-    assert sc.routed_shortcut >= 4
-    assert sc.routed_traditional >= 4
+    assert sc.routed_shortcut == 4
+    assert sc.routed_traditional == 4
 
 
 def test_train_crash_restore_bitwise(tmp_path):

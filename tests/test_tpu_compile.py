@@ -67,16 +67,13 @@ def _args(sharding, entry: str, n: int):
         "sharded_eh_lookup": (keys, *trad),
         "sharded_shortcut_lookup": (keys, *view),
         "sharded_routed_lookup": (keys, *trad, *view, s((n,), jnp.int32)),
-        "stacked_shortcut_lookup": (s((4096,), jnp.uint32), *view,
-                                    s((), jnp.int32)),
     }[entry]
 
 
 @pytest.mark.parametrize("n", [1, 16])
 @pytest.mark.parametrize("entry", ["sharded_eh_lookup",
                                    "sharded_shortcut_lookup",
-                                   "sharded_routed_lookup",
-                                   "stacked_shortcut_lookup"])
+                                   "sharded_routed_lookup"])
 def test_lookup_compiles_for_v5e(one_chip, entry, n):
     fn = functools.partial(getattr(kernels, entry), interpret=False)
     compiled = jax.jit(fn).lower(*_args(one_chip, entry, n)).compile()
@@ -103,21 +100,18 @@ def _args64(sharding, entry: str):
         "sharded_eh_lookup": (keys, *trad),
         "sharded_shortcut_lookup": (keys, *view),
         "sharded_routed_lookup": (keys, *trad, *view, s((1,), jnp.int32)),
-        "stacked_shortcut_lookup": (s((2, 4096), jnp.uint32), *view,
-                                    s((), jnp.int32)),
     }[entry]
 
 
 @pytest.mark.parametrize("entry", ["sharded_eh_lookup",
                                    "sharded_shortcut_lookup",
-                                   "sharded_routed_lookup",
-                                   "stacked_shortcut_lookup"])
+                                   "sharded_routed_lookup"])
 def test_lookup_compiles_for_v5e_at_64_bits(one_chip, entry):
     fn = functools.partial(getattr(kernels, entry), interpret=False)
     compiled = jax.jit(fn).lower(*_args64(one_chip, entry)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     out = compiled.memory_analysis().output_size_in_bytes
-    assert out >= 2 * 4 * (4096 if entry.startswith("stacked") else 1 << 16)
+    assert out >= 2 * 4 * (1 << 16)
 
 
 def test_insert_many_compiles_for_v5e(one_chip):

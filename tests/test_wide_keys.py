@@ -4,9 +4,9 @@ uint64 keys and values whose 32-bit words are never 0 or all ones.
 
 Covered at 64 bits: ``eh_insert_many`` (fresh keys through splits and
 directory doublings, then overwrites with the last occurrence winning),
-``ShortcutEH``, ``ShardedShortcutEH`` at one and two shards on both
-forced routes of ``lookup_batched``, the Pallas kernels in interpret
-mode against their XLA twin, and keys that share one word with a stored
+``ShardedShortcutEH`` at one and two shards on both forced routes of
+``lookup_batched``, the Pallas kernels in interpret mode against their
+XLA twin, and keys that share one word with a stored
 key (each must read the miss marker).  At 32 bits one test pins results
 and state to what they were before wide keys."""
 import hashlib
@@ -20,36 +20,15 @@ import pytest
 from repro.core import extendible_hashing as eh
 from repro.core import hashing
 from repro.core.sharded_eh import ShardedShortcutEH
-from repro.core.shortcut_eh import ShortcutEH
 from repro.kernels import eh_lookup as kernels
 from repro.kernels import ref
 
+from conftest import miss_probe, wide_keys
+
 MISS64 = 2**64 - 1
-LOW = np.uint64(0xFFFFFFFF)
 # one geometry and batches of 400 and 200 pairs throughout, so that
 # the tests share their compiled programs
 GEOMETRY = (9, 8, 512)          # max global depth, slots, capacity
-
-
-def wide(rng, n, bits=64):
-    """``n`` distinct keys (or values) of ``bits`` bits, every 32-bit
-    word in [1, 2**32 - 2]."""
-    out = np.zeros(0, np.uint64)
-    while out.size < n:
-        w = rng.integers(1, 2**32 - 1, (bits // 32, 2 * n), dtype=np.uint64)
-        k = w[0] if bits == 32 else (w[0] << np.uint64(32)) | w[1]
-        out = np.unique(np.concatenate([out, k]))
-    return rng.permutation(out)[:n].astype(hashing.word_type(bits))
-
-
-def miss_probe(rng, stored):
-    """Never-stored keys, half sharing their low word with a stored key,
-    half their high word."""
-    pick = rng.choice(stored, 64)
-    fresh = wide(rng, 64, 32).astype(np.uint64)
-    probe = np.concatenate([(pick[:32] & LOW) | (fresh[:32] << np.uint64(32)),
-                            (pick[32:] & ~LOW) | fresh[32:]])
-    return probe[~np.isin(probe, stored)]
 
 
 def expect(table, keys):
@@ -66,8 +45,8 @@ def loaded():
     doublings, then a batch of overwrites with repeats and a few fresh
     keys; the dict that matches it; and the base state."""
     rng = np.random.default_rng(64)
-    keys = wide(rng, 450)
-    vals = wide(rng, 450)
+    keys = wide_keys(rng, 450)
+    vals = wide_keys(rng, 450)
     base = eh.eh_create(*GEOMETRY, key_bits=64, value_bits=64)
     st, fresh, _ = eh.eh_insert_many(base, device_words(keys[:400]),
                                      device_words(vals[:400]))
@@ -138,10 +117,12 @@ class TestKernels64:
         np.testing.assert_array_equal(
             np.asarray(ref.shortcut_lookup_ref(pw, vk, vv, st.global_depth)),
             np.asarray(want))
-        trad = kernels.eh_lookup(pw, st.directory[:D], st.bucket_keys,
-                                 st.bucket_vals, st.global_depth, tile=128)
-        short = kernels.shortcut_lookup(pw, vk, vv, st.global_depth,
-                                        tile=128)
+        one = lambda a: jnp.asarray(a)[None]                  # noqa: E731
+        trad = kernels.sharded_eh_lookup(
+            one(pw), one(st.directory[:D]), one(st.bucket_keys),
+            one(st.bucket_vals), one(st.global_depth), tile=128)[0]
+        short = kernels.sharded_shortcut_lookup(
+            one(pw), one(vk), one(vv), one(st.global_depth), tile=128)[0]
         np.testing.assert_array_equal(np.asarray(trad), np.asarray(want))
         np.testing.assert_array_equal(np.asarray(short), np.asarray(want))
         two = lambda a: jnp.stack([a, a])                     # noqa: E731
@@ -194,8 +175,9 @@ def _routes(idx, route):
 
 class TestIndex64:
     def test_shortcut_eh(self, rng):
-        keys, vals = wide(rng, 400), wide(rng, 400)
-        with ShortcutEH(*GEOMETRY, key_bits=64, value_bits=64) as sc:
+        keys, vals = wide_keys(rng, 400), wide_keys(rng, 400)
+        with ShardedShortcutEH(*GEOMETRY, num_shards=1, key_bits=64,
+                               value_bits=64) as sc:
             sc.insert(keys, vals)
             table = dict(zip(keys.tolist(), vals.tolist()))
             upd = rng.choice(keys, 200)
@@ -203,19 +185,19 @@ class TestIndex64:
             sc.insert(upd, uv)
             table.update(zip(upd.tolist(), uv.tolist()))
             probe = np.concatenate([keys, miss_probe(rng, keys)])
-            got = sc.lookup(probe)                      # stale: traditional
+            got = sc.lookup_batched(probe)              # stale: traditional
             assert got.dtype == np.uint64
             np.testing.assert_array_equal(got, expect(table, probe))
+            assert sc.routed_shortcut == 0 and sc.routed_traditional == 1
             sc.pump()
-            assert sc.use_shortcut()
-            np.testing.assert_array_equal(sc.lookup(probe),
+            np.testing.assert_array_equal(sc.lookup_batched(probe),
                                           expect(table, probe))
             assert sc.routed_shortcut == 1 and sc.routed_traditional == 1
             assert sc.split_seconds > 0
 
     @pytest.mark.parametrize("num_shards", [1, 2])
     def test_sharded_both_forced_routes(self, rng, num_shards):
-        keys, vals = wide(rng, 400), wide(rng, 400)
+        keys, vals = wide_keys(rng, 400), wide_keys(rng, 400)
         table = dict(zip(keys.tolist(), vals.tolist()))
         with ShardedShortcutEH(*GEOMETRY, num_shards=num_shards,
                                key_bits=64, value_bits=64) as idx:
@@ -237,13 +219,12 @@ class TestIndex64:
                 assert got.dtype == np.uint64
                 np.testing.assert_array_equal(got, want, err_msg=route)
                 assert (idx.routed_shortcut > sc0) == (route == "shortcut")
-                np.testing.assert_array_equal(idx.lookup(probe), want)
             before = idx.split_seconds
             idx.lookup_batched(probe)
             assert idx.split_seconds > before
 
     def test_wide_keys_with_narrow_values(self, rng):
-        keys = wide(rng, 300)
+        keys = wide_keys(rng, 300)
         vals = rng.integers(0, 2**32 - 1, 300, dtype=np.uint32)
         with ShardedShortcutEH(8, 8, 256, num_shards=2,
                                key_bits=64) as idx:
@@ -270,7 +251,6 @@ class TestNoWrap:
         with ShardedShortcutEH(*GEOMETRY, key_bits=bits,
                                value_bits=bits) as idx:
             for call in (lambda: idx.insert(bad, ok),
-                         lambda: idx.lookup(bad),
                          lambda: idx.lookup_batched(bad)):
                 with pytest.raises(ValueError, match=f"{bits}-bit key"):
                     call()
